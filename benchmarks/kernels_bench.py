@@ -20,18 +20,19 @@ from benchmarks.common import csv_row, timeit_us, tiny_backbone
 from repro.core.hardware_model import DEFAULT_TPU
 from repro.kernels import autotune, dispatch
 
-KEY = jax.random.PRNGKey(0)
+SEED = 0
 
-# backends benchmarkable on this host ("pallas-tpu" needs TPU hardware)
-_HOST_BACKENDS = (
-    ("pallas-tpu", "pallas-interpret", "reference")
-    if jax.default_backend() == "tpu"
-    else ("pallas-interpret", "reference")
-)
+
+def _host_backends():
+    """Backends benchmarkable on this host ("pallas-tpu" needs TPU
+    hardware); asked at run time, never on import."""
+    if jax.default_backend() == "tpu":
+        return ("pallas-tpu", "pallas-interpret", "reference")
+    return ("pallas-interpret", "reference")
 
 
 def _chimera_args(B=1, Hkv=2, Gq=1, T=512, d=32, m=64, dv=32):
-    ks = jax.random.split(KEY, 5)
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 5)
     return (
         jax.random.normal(ks[0], (B, Hkv, Gq, T, d)),
         jax.random.normal(ks[1], (B, Hkv, T, d)),
@@ -42,7 +43,7 @@ def _chimera_args(B=1, Hkv=2, Gq=1, T=512, d=32, m=64, dv=32):
 
 
 def _decode_args(BH=8, Gq=1, L=128, d=32, m=64, dv=32):
-    ks2 = jax.random.split(KEY, 9)
+    ks2 = jax.random.split(jax.random.PRNGKey(SEED), 9)
     return (
         jax.random.normal(ks2[0], (BH, Gq, d)),
         jax.random.normal(ks2[1], (BH, d)),
@@ -59,10 +60,11 @@ def _decode_args(BH=8, Gq=1, L=128, d=32, m=64, dv=32):
 
 def kernel_benchmarks() -> List[str]:
     rows = []
+    backends = _host_backends()
     B, Hkv, Gq, T, d, m, dv, L = 1, 2, 1, 512, 32, 64, 32, 128
     q, k, v, pq, pk = _chimera_args(B, Hkv, Gq, T, d, m, dv)
 
-    for backend in _HOST_BACKENDS:
+    for backend in backends:
         impl = dispatch.resolve("chimera_attention", backend)
         fn = jax.jit(lambda *a, _i=impl: _i(*a, chunk_size=L))
         us = timeit_us(fn, q, k, v, pq, pk, iters=5)
@@ -76,7 +78,7 @@ def kernel_benchmarks() -> List[str]:
 
     kw = k.reshape(B * Hkv, T, d)
     vw = v.reshape(B * Hkv, T, dv)
-    for backend in _HOST_BACKENDS:
+    for backend in backends:
         impl = dispatch.resolve("window_attention", backend)
         fn = jax.jit(lambda *a, _i=impl: _i(*a, window=128, blk_q=128, blk_k=128))
         us = timeit_us(fn, kw, kw, vw, iters=5)
@@ -84,7 +86,7 @@ def kernel_benchmarks() -> List[str]:
 
     BH = 8
     args = _decode_args(BH, Gq, L, d, m, dv)
-    for backend in _HOST_BACKENDS:
+    for backend in backends:
         impl = dispatch.resolve("decode_step", backend)
         fn = jax.jit(lambda *a, _i=impl: _i(*a, chunk_size=L))
         us = timeit_us(fn, *args, iters=5)
@@ -166,7 +168,7 @@ def serving_benchmarks() -> List[str]:
 
     rows = []
     cfg = tiny_backbone()
-    params, _ = M.init_model(cfg, KEY)
+    params, _ = M.init_model(cfg, jax.random.PRNGKey(SEED))
     import time
 
     for slots in (1, 4, 8):

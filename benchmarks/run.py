@@ -21,6 +21,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import kernels_bench, serve_bench, tables
+    from repro.launch.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # classification benches run in the pre-saturation regime (the synthetic
     # proxy task saturates to F1=1.0 for every method given enough steps —
@@ -58,17 +61,23 @@ def main() -> None:
         suites = {k: v for k, v in suites.items() if k not in drop}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         t0 = time.time()
         try:
             for row in fn():
                 print(row, flush=True)
         except Exception as e:  # noqa: BLE001
+            failed.append(name)
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
             import traceback
 
             traceback.print_exc(file=sys.stderr)
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr, flush=True)
+    if failed:
+        print(f"{len(failed)} suite(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -40,7 +40,7 @@ import os
 import subprocess
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,17 +55,43 @@ from repro.train import classifier as C
 # backends runnable on this host; "xla" is the pure-jnp decode path, the
 # rest route the per-packet step through repro.kernels.dispatch
 _BACKENDS_FAST = ("xla", "reference", "int-emulation")
-_BACKENDS_FULL = ("xla", "reference", "pallas-interpret", "int-emulation") + (
-    ("pallas-tpu",) if jax.default_backend() == "tpu" else ()
-)
+
+
+def _backends_full():
+    """The full backend sweep; ``pallas-tpu`` only where a TPU is attached
+    (asked at run time, never on import)."""
+    return ("xla", "reference", "pallas-interpret", "int-emulation") + (
+        ("pallas-tpu",) if _on_chip() else ()
+    )
+
+
+def _on_chip() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+class SweepFailed(RuntimeError):
+    """Raised by a device sweep once it has yielded all its rows (one ERROR
+    row per failed worker among them), so every caller both keeps the
+    partial results and fails."""
+
+
+def _fit_devices(label: str, want: int) -> int:
+    """Devices a chip-side point gets: ``want``, or the chip count with a
+    note on stderr when the host has fewer."""
+    have = jax.device_count()
+    if want > have:
+        print(f"{label}: {want} devices asked, this host has {have}",
+              file=sys.stderr)
+    return min(want, have)
+
 
 _SCENARIOS_FAST = ("protocol-mix", "port-scan")
 _SCENARIOS_FULL = (
     "protocol-mix", "port-scan", "burst", "heavy-churn", "rule-violating",
 )
 
-# sharded sweep: device counts measured (each in its own subprocess with
-# that many forced host-platform devices)
+# sharded sweep: device counts measured (in-process on a chip; on the CPU
+# each in its own subprocess with that many forced host-platform devices)
 _SHARDS_FAST = (1, 2)
 _SHARDS_FULL = (1, 2, 4, 8)
 
@@ -104,7 +130,7 @@ def serve_flow_benchmarks(fast: bool = False) -> List[str]:
     from repro.serve.ingest_pipeline import AsyncIngestPipeline
 
     rows: List[str] = []
-    backends = _BACKENDS_FAST if fast else _BACKENDS_FULL
+    backends = _BACKENDS_FAST if fast else _backends_full()
     scenarios = _SCENARIOS_FAST if fast else _SCENARIOS_FULL
     batches = 3 if fast else 6
     ccfg, params = _build()
@@ -359,15 +385,24 @@ def _sharded_worker_rows(num_shards: int, fast: bool) -> List[str]:
     return rows
 
 
-def serve_flow_sharded_benchmarks(fast: bool = False) -> List[str]:
+def serve_flow_sharded_benchmarks(fast: bool = False) -> Iterator[str]:
     """Sweep pkts/sec + resident flows vs device count (1/2/4/8 shards).
 
-    Each point runs ``--sharded-worker N`` in a subprocess with
+    On a chip every point that fits ``jax.devices()`` runs in this process
+    (a chip belongs to one process).  On the CPU, as a rehearsal, each
+    point runs ``--sharded-worker N`` in a subprocess with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — the device
-    count is fixed at jax init, so one process cannot sweep it."""
-    rows: List[str] = []
+    count is fixed at jax init, so one process cannot sweep it.  Raises
+    :class:`SweepFailed` after the last row if any worker failed."""
+    counts = _SHARDS_FAST if fast else _SHARDS_FULL
+    if _on_chip():
+        for n in counts:
+            if _fit_devices(f"serve/flow_sharded/shards{n}: skipped", n) == n:
+                yield from _sharded_worker_rows(n, fast)
+        return
+    failed = []
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for n in _SHARDS_FAST if fast else _SHARDS_FULL:
+    for n in counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
@@ -385,20 +420,23 @@ def serve_flow_sharded_benchmarks(fast: bool = False) -> List[str]:
         )
         if proc.returncode != 0:
             # the ERROR row keeps the sweep's partial results printable,
-            # and main() turns any ERROR row into a nonzero exit so a
-            # broken ShardedFlowEngine fails the CI smoke gate instead of
-            # silently vanishing from the regression gate's name set
+            # and SweepFailed fails the run so a broken ShardedFlowEngine
+            # fails the CI smoke gate instead of silently vanishing from
+            # the regression gate's name set
             err_lines = (proc.stderr or "").strip().splitlines()
-            rows.append(csv_row(
+            yield csv_row(
                 f"serve/flow_sharded/ERROR/shards{n}", 0.0,
                 err_lines[-1] if err_lines else "worker failed",
-            ))
+            )
+            failed.append(n)
             continue
-        rows.extend(
+        yield from (
             line for line in proc.stdout.splitlines()
             if line.startswith("serve/flow_sharded/")
         )
-    return rows
+    if failed:
+        raise SweepFailed(f"serve/flow_sharded: worker(s) for shards "
+                          f"{failed} failed")
 
 
 # --------------------------------------------------------------------------
@@ -465,10 +503,16 @@ def _elastic_worker_rows(devices: int, fast: bool) -> List[str]:
     return rows
 
 
-def serve_elastic_benchmarks(fast: bool = False) -> List[str]:
-    """Elastic reshard cycle in a subprocess with forced host devices
-    (2 fast / 8 full), so the sweep runs on single-device CI hosts too."""
+def serve_elastic_benchmarks(fast: bool = False) -> Iterator[str]:
+    """Elastic reshard cycle over the chip's own devices, in this process;
+    on the CPU, as a rehearsal, in a subprocess with forced host devices
+    (2 fast / 8 full), so the sweep runs on single-device CI hosts too.
+    Raises :class:`SweepFailed` after its ERROR row if the worker failed."""
     devices = 2 if fast else 8
+    if _on_chip():
+        yield from _elastic_worker_rows(
+            _fit_devices("serve/elastic: cycle shrunk", devices), fast)
+        return
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
@@ -487,12 +531,13 @@ def serve_elastic_benchmarks(fast: bool = False) -> List[str]:
     )
     if proc.returncode != 0:
         err_lines = (proc.stderr or "").strip().splitlines()
-        return [csv_row(
+        yield csv_row(
             f"serve/elastic/ERROR/devices{devices}", 0.0,
             err_lines[-1] if err_lines else "worker failed",
-        )]
-    return [line for line in proc.stdout.splitlines()
-            if line.startswith("serve/elastic/")]
+        )
+        raise SweepFailed(f"serve/elastic: worker for {devices} devices failed")
+    yield from (line for line in proc.stdout.splitlines()
+                if line.startswith("serve/elastic/"))
 
 
 # --------------------------------------------------------------------------
@@ -552,6 +597,8 @@ def check_regression(
 
 
 def main() -> None:
+    from repro.launch.jax_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--json", default=None, metavar="PATH",
@@ -597,6 +644,8 @@ def main() -> None:
         print(f"serve-bench regression gate OK ({args.gate} vs {args.baseline})")
         return
 
+    enable_compile_cache()
+    failures: List[str] = []
     if args.sharded_worker:
         rows = _sharded_worker_rows(args.sharded_worker, fast=args.fast)
     elif args.elastic_worker:
@@ -609,20 +658,24 @@ def main() -> None:
             rows += serve_adaptive_benchmarks(fast=args.fast)
         if args.suite in ("redteam", "all"):
             rows += serve_redteam_benchmarks(fast=args.fast)
-        if args.suite in ("sharded", "all"):
-            rows += serve_flow_sharded_benchmarks(fast=args.fast)
-        if args.suite in ("elastic", "all"):
-            rows += serve_elastic_benchmarks(fast=args.fast)
+        for suite, sweep in (("sharded", serve_flow_sharded_benchmarks),
+                             ("elastic", serve_elastic_benchmarks)):
+            if args.suite not in (suite, "all"):
+                continue
+            try:
+                for row in sweep(fast=args.fast):
+                    rows.append(row)
+            except SweepFailed as e:
+                failures.append(str(e))
     print("name,us_per_call,derived")
     for row in rows:
         print(row, flush=True)
     if args.json:
         write_json(rows, args.json)
-    errors = [r for r in rows if "/ERROR/" in r.split(",", 1)[0]]
-    if errors:
-        print(f"{len(errors)} benchmark worker(s) FAILED:", file=sys.stderr)
-        for r in errors:
-            print(f"  {r}", file=sys.stderr)
+    if failures:
+        print(f"{len(failures)} benchmark sweep(s) FAILED:", file=sys.stderr)
+        for msg in failures:
+            print(f"  {msg}", file=sys.stderr)
         sys.exit(1)
 
 

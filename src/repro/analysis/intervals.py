@@ -406,13 +406,13 @@ def _is_inner_literal(v) -> bool:
 
 def _nested_jaxpr(eqn):
     """The single sub-jaxpr of call-like primitives the interpreter
-    descends into transparently (pjit / closed_call / remat / custom_*).
+    descends into transparently (jit / closed_call / remat / custom_*).
     Control-flow primitives with *multiple* bodies (cond, scan, while) are
     NOT modeled — they fall to the conservative dtype-range default."""
     from jax.extend import core as jex_core
 
     if eqn.primitive.name in (
-        "pjit", "closed_call", "remat", "checkpoint", "custom_jvp_call",
+        "jit", "closed_call", "remat", "checkpoint", "custom_jvp_call",
         "custom_vjp_call", "custom_vjp_call_jaxpr",
     ):
         for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):

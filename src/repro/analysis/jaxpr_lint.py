@@ -23,7 +23,7 @@ that share it:
 
 The walker recurses through equation params into sub-jaxprs held in
 arbitrarily nested tuples / lists / **dicts** (``cond`` branches, ``scan``
-bodies, ``pjit`` calls, ``custom_vjp`` closures, and any future primitive
+bodies, ``jit`` calls, ``custom_vjp`` closures, and any future primitive
 that nests them deeper), which the old ``_walk_jaxpr`` only scanned one
 container level deep.  ``compile.int_lowering`` re-exports the promoted
 helpers so existing imports keep working.
@@ -83,7 +83,7 @@ def walk_jaxpr(jaxpr, visit: Callable, path: str = "") -> None:
     """Apply ``visit(eqn, path)`` to every equation of ``jaxpr`` and of
     every sub-jaxpr reachable through equation params — however deeply the
     params nest them in tuples/lists/dicts (``cond`` branch tuples,
-    ``scan``/``pjit``/``while`` bodies, ``custom_vjp`` closures, ...).
+    ``scan``/``jit``/``while`` bodies, ``custom_vjp`` closures, ...).
 
     ``path`` accumulates the primitive nesting ("scan/cond") so findings
     can say *where* in the program they fired.

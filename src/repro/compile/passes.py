@@ -230,11 +230,12 @@ def select_backend(
     """Resolve the kernel backend and (for dispatch backends) look up the
     autotuned decode tiles; record the VMEM working set against the TPU's
     SRAM-tier budget (the on-host Eq. 11 analogue)."""
+    from repro.core.chimera_attention import decode_kernel_gap
     from repro.kernels import autotune
     from repro.kernels.dispatch import apply_kernel_backend, resolve_backend
 
     arch = ccfg.arch
-    _, effective = apply_kernel_backend(arch, backend)  # fails fast on typos
+    kernel_arch, effective = apply_kernel_backend(arch, backend)  # fails fast on typos
     ch = arch.chimera
     dims = {
         "d": arch.head_dim,
@@ -246,9 +247,10 @@ def select_backend(
     tiles: Optional[Dict[str, int]] = None
     # int-emulation keeps the backbone on the plain-jnp path (only the score
     # stage is lowered), so there is no Pallas decode kernel to tile
-    if effective not in (None, "xla", "int-emulation"):
+    dispatched = effective not in (None, "xla", "int-emulation")
+    if dispatched:
         tiles = autotune.get_tiles(
-            "decode_step", dims, backend=resolve_backend(effective)
+            "decode_step", dims, backend=resolve_backend(effective), spec=tpu
         )
     probe = tiles or {"chunk_size": ch.chunk_size}
     vmem = autotune.vmem_bytes("decode_step", probe, dims)
@@ -262,6 +264,16 @@ def select_backend(
                    f"(decode_step working set, double-buffered)",
         )
     ]
+    gap = decode_kernel_gap(kernel_arch)
+    if dispatched and gap is not None:
+        # a kernel backend was asked for, but the decode runs on XLA: say so
+        entries.append(StageEntry(
+            stage="kernel-backend",
+            resource="xla-decode-layers",
+            used=arch.n_layers,
+            budget=arch.n_layers,
+            detail=f"backend={effective}: decode runs the jnp (XLA) path — {gap}",
+        ))
     return effective, tiles, entries
 
 

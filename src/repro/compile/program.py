@@ -36,7 +36,7 @@ from repro.configs.base import ArchConfig
 from repro.core import symbolic
 from repro.core.chimera_attention import ChimeraAttentionConfig
 from repro.core.feature_maps import FeatureMapConfig
-from repro.core.hardware_model import DEFAULT_DATAPLANE, DEFAULT_TPU, DataplaneSpec
+from repro.core.hardware_model import DEFAULT_DATAPLANE, DataplaneSpec, device_tpu_spec
 from repro.core.quantization import FixedPointSpec
 from repro.core.state_quant import StateQuantConfig
 from repro.train.classifier import ClassifierConfig
@@ -230,7 +230,7 @@ def compile_program(
     horizon: int = 1024,
     flows: int = 8192,
     waivers: Tuple[str, ...] = (),
-    tpu=DEFAULT_TPU,
+    tpu=None,
     int_cfg=None,
     verify: bool = True,
 ) -> DataplaneProgram:
@@ -244,6 +244,10 @@ def compile_program(
     Raises :class:`BudgetError` naming the offending stage when any pass
     exceeds ``spec``, unless that stage is listed in ``waivers`` (the
     violation is then recorded in the ledger instead).
+
+    ``tpu`` is the chip the kernel pass budgets against; ``None`` looks it
+    up by the present device's ``device_kind``
+    (:func:`repro.core.hardware_model.device_tpu_spec`).
 
     ``verify`` (on by default) runs the static-verification battery
     (:func:`repro.analysis.verify.verify_program`) as a final pass: TCAM
@@ -277,7 +281,9 @@ def compile_program(
     ledger.extend(entries)
 
     # pass 4 — kernel backend + tiles
-    effective_backend, tiles, entries = passes.select_backend(ccfg, backend, tpu)
+    effective_backend, tiles, entries = passes.select_backend(
+        ccfg, backend, tpu if tpu is not None else device_tpu_spec()
+    )
     ledger.extend(entries)
 
     # pass 4b — integer score lowering (int-emulation targets only): derive

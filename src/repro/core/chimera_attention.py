@@ -316,6 +316,29 @@ def prefill_into_state(
     )
 
 
+def decode_kernel_gap(arch) -> Optional[str]:
+    """Why a model's per-token decode under ``arch`` (an ``ArchConfig``)
+    runs the jnp (XLA) path instead of the fused ``decode_step`` kernel;
+    ``None`` when it takes the kernel.  The one place that makes this
+    choice, so the compiler's ledger and the engines report what actually
+    runs."""
+    if not arch.use_chimera:
+        return "softmax attention has no decode_step kernel"
+    return _chimera_kernel_gap(arch.chimera)
+
+
+def _chimera_kernel_gap(cfg: ChimeraAttentionConfig) -> Optional[str]:
+    """:func:`decode_kernel_gap` for a Chimera layer; what
+    :func:`chimera_decode_step` branches on."""
+    if not cfg.use_pallas:
+        return "no kernel backend selected"
+    if not (cfg.use_local and cfg.use_stream):
+        return "the decode_step kernel fuses local + stream; this config ablates one"
+    if cfg.n_global > 0:
+        return f"the global TCAM tier (n_global={cfg.n_global}) has no decode_step kernel"
+    return None
+
+
 def chimera_decode_step(
     cfg: ChimeraAttentionConfig,
     params: Params,
@@ -353,7 +376,7 @@ def chimera_decode_step(
     k_buf = jnp.where(slot, kh[:, :, None, :], state.k_buf)
     v_buf = jnp.where(slot, v_t[:, :, None, :], state.v_buf)
 
-    if cfg.use_pallas and cfg.use_local and cfg.use_stream and cfg.n_global == 0:
+    if _chimera_kernel_gap(cfg) is None:
         # fused per-packet program through the dispatch registry: the kernel
         # performs ring write / local / stream / merge / fold in one pass
         # (it receives the PRE-write buffers and redoes the slot write)

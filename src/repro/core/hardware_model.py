@@ -28,7 +28,7 @@ class DataplaneSpec:
 
 @dataclasses.dataclass(frozen=True)
 class TPUSpec:
-    """Per-chip roofline constants (given by the brief; v5e-class)."""
+    """Per-chip roofline constants; one row of :data:`TPU_SPECS`."""
 
     name: str = "tpu-v5e"
     peak_flops_bf16: float = 197e12  # FLOP/s
@@ -38,10 +38,46 @@ class TPUSpec:
     hbm_bytes: int = 16 * 2 ** 30
     vmem_bytes: int = 128 * 2 ** 20  # v5e has ~128MiB VMEM total (per core ~64MiB usable)
     mxu_dim: int = 128  # systolic array edge; matmul dims should align
+    source: str = ""
 
+
+#: Chip constants keyed by ``jax.Device.device_kind``.  A kind missing here
+#: is an error on the chip path (:func:`device_tpu_spec`), never a default.
+TPU_SPECS = {
+    "TPU v5 lite": TPUSpec(
+        name="tpu-v5e",
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GiB HBM2 at 819 GB/s, 1,600 Gbit/s ICI over 4 links "
+               "(VMEM and MXU edge are not in that table)",
+    ),
+}
 
 DEFAULT_DATAPLANE = DataplaneSpec()
-DEFAULT_TPU = TPUSpec()
+DEFAULT_TPU = TPU_SPECS["TPU v5 lite"]
+
+
+def tpu_spec_for(device_kind: str) -> TPUSpec:
+    """The :data:`TPU_SPECS` row of a ``device_kind``; raises on an unknown
+    kind rather than guessing another chip's constants."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TPUSpec for device_kind {device_kind!r}; known kinds: "
+            f"{sorted(TPU_SPECS)}"
+        ) from None
+
+
+def device_tpu_spec() -> TPUSpec:
+    """Constants of the chip this process runs on, looked up by
+    ``device_kind`` on a TPU.  Any other platform is a rehearsal of the v5e
+    target, so it gets :data:`DEFAULT_TPU`."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return DEFAULT_TPU
+    return tpu_spec_for(dev.device_kind)
 
 
 # --------------------------------------------------------------------------

@@ -86,7 +86,8 @@ def _kernel(
     Z_out[0, :] = (Z + full * (Z_fold - Z)).astype(Z_out.dtype)
     kbuf_out[...] = ((1.0 - full) * kbuf).astype(kbuf_out.dtype)
     vbuf_out[...] = ((1.0 - full) * vbuf).astype(vbuf_out.dtype)
-    count_out[0, 0] = jnp.where(c + 1 >= L, 0, c + 1)
+    # a (1, 1) vector store: Mosaic cannot store a scalar to VMEM
+    count_out[...] = jnp.full((1, 1), jnp.where(c + 1 >= L, 0, c + 1), jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_size", "gamma", "interpret"))

@@ -42,9 +42,6 @@ from repro.core import fusion as fusion_mod
 from repro.core import symbolic
 from repro.models import layers
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _round_up(n: int, k: int) -> int:
     return ((n + k - 1) // k) * k
@@ -212,7 +209,7 @@ def flow_ingest_scores_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
 

@@ -28,9 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(
     q_ref,  # (Bq, d)
@@ -129,7 +126,7 @@ def window_attention_pallas(
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -4,7 +4,9 @@ Backend selection goes through :mod:`repro.kernels.dispatch`; tile sizes
 default to the autotuner (:mod:`repro.kernels.autotune`) — a cache hit
 returns benchmark-tuned (blk_q, blk_k), a miss returns the MXU-aligned
 heuristic.  Shapes no admissible tile covers (T or window not divisible by
-any tile) fall back to the exact reference, as does ``backend="reference"``.
+any tile) fall back to the exact reference, as does ``backend="reference"``
+— except under an explicit ``backend="pallas-tpu"``, which raises rather
+than run something other than the kernel it asked for.
 
 Like the chimera ops, the Pallas forward is wrapped in ``jax.custom_vjp``
 with the reference formulation as the backward pass (pallas_call is not
@@ -72,15 +74,21 @@ def sliding_window_attention(
         if tiles is not None:
             blk_q = tiles["blk_q"] if blk_q is None else blk_q
             blk_k = tiles["blk_k"] if blk_k is None else blk_k
-    if (
-        concrete == "reference"
-        or blk_q is None
+    untileable = (
+        blk_q is None
         or blk_k is None
         or T % blk_q != 0
         or T % blk_k != 0
         or window % blk_k != 0
         or blk_q % blk_k != 0
-    ):
+    )
+    if untileable and backend == "pallas-tpu":
+        raise ValueError(
+            f"window_attention: backend='pallas-tpu' was requested but no "
+            f"tile covers T={T}, window={window} (blk_q={blk_q}, "
+            f"blk_k={blk_k}); use backend='auto' to allow the reference path"
+        )
+    if concrete == "reference" or untileable:
         # shape fallback: exact reference (still O(T·T); used for tiny tests)
         concrete, blk_q, blk_k = "reference", 0, 0
     out = _window_attention(

@@ -56,9 +56,45 @@ chimera-trace-v1 file through :class:`~repro.data.traces
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
+
+
+def classifier_config(arch_name: str = "chimera-dataplane", smoke: bool = False):
+    """The served classifier: the registry config (``smoke`` = its reduced
+    preset), with the vocab widened to the byte + marker alphabet."""
+    from repro.configs import get_config, smoke_config
+    from repro.train import classifier as C
+
+    arch = smoke_config(arch_name) if smoke else get_config(arch_name)
+    arch = dataclasses.replace(arch, vocab_size=max(arch.vocab_size, 512))
+    return C.ClassifierConfig(arch=arch, n_classes=8, marker_base=256)
+
+
+def compile_flow_program(ccfg, anomaly_signature, *, backend=None,
+                         smoke: bool = False, seed: int = 0):
+    """Random classifier weights from ``seed``, compiled against the default
+    rules for ``anomaly_signature``.  The compiler's signature-layout pass
+    sizes sig_words so every marker owns a TCAM bit; the rules callable sees
+    the finalized layout.  The full arch intentionally exceeds the 1KB/flow
+    switch budget (Table 2 amortizes it over shared SRAM banks), so the
+    per-flow stage is waived for this TPU-host deployment — recorded in the
+    ledger, not dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.compile import compile_program
+    from repro.train import classifier as C
+
+    params, _ = C.init_classifier(ccfg, jax.random.PRNGKey(seed))
+    return compile_program(
+        ccfg, params,
+        rules=lambda c: C.default_rules(c, jnp.asarray(anomaly_signature)),
+        backend=backend,
+        waivers=() if smoke else ("state-quantization",),
+    )
 
 
 def main() -> None:
@@ -139,23 +175,14 @@ def main() -> None:
             + f" --xla_force_host_platform_device_count={args.host_devices}"
         ).strip()
 
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.compile import compile_program
-    from repro.configs import get_config, smoke_config
     from repro.data.pipeline import DriftScenario, FlowScenario, parse_phases
+    from repro.launch.jax_cache import enable_compile_cache
     from repro.serve.deploy import DeploySpec, ElasticConfig
     from repro.serve.flow_engine import FlowEngineConfig
-    from repro.train import classifier as C
 
-    arch = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    vocab = max(arch.vocab_size, 512)  # byte + marker alphabet
-    arch = dataclasses.replace(arch, vocab_size=vocab)
-    ccfg = C.ClassifierConfig(arch=arch, n_classes=8, marker_base=256)
-    params, _ = C.init_classifier(ccfg, jax.random.PRNGKey(0))
+    enable_compile_cache()
+    ccfg = classifier_config(args.arch, smoke=args.smoke)
+    vocab = ccfg.arch.vocab_size
 
     if args.campaign and args.trace:
         ap.error("--campaign and --trace are mutually exclusive")
@@ -195,19 +222,10 @@ def main() -> None:
         scenario = FlowScenario(kind=args.scenario, vocab_size=vocab,
                                 pkt_len=args.pkt_len,
                                 packets_per_batch=args.packets, seed=0)
-    # the compiler's signature-layout pass sizes sig_words so every marker
-    # owns a TCAM bit; the rules callable sees the finalized layout.  The
-    # full arch intentionally exceeds the 1KB/flow switch budget (Table 2
-    # amortizes it over shared SRAM banks), so the per-flow stage is waived
-    # for this TPU-host deployment — recorded in the ledger, not dropped.
-    program = compile_program(
-        ccfg, params,
-        rules=lambda c: C.default_rules(c, jnp.asarray(scenario.anomaly_signature)),
-        backend=args.backend,
-        waivers=() if args.smoke else ("state-quantization",),
+    program = compile_flow_program(
+        ccfg, scenario.anomaly_signature, backend=args.backend,
+        smoke=args.smoke,
     )
-    if args.ledger:
-        print(program.ledger.as_table())
     if args.save_program:
         program.save(args.save_program)
         print(f"program saved to {args.save_program}")
@@ -240,6 +258,11 @@ def main() -> None:
     else:
         spec = DeploySpec(flow=fcfg)
     engine = program.deploy(spec)
+    if args.ledger:
+        print(program.ledger.as_table())
+    print("stages: " + ", ".join(
+        f"{k}={v}" for k, v in engine.stage_impls.items()
+    ))
     loop = None
     if args.adapt:
         from repro.serve.adaptive_loop import (

@@ -11,20 +11,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist on
-    # newer jax releases; fall back to an explicit device-array Mesh
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        import math
-
-        import numpy as np
-
-        n = math.prod(shape)
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devs, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -58,20 +47,9 @@ def make_flow_mesh(num_shards: "int | None" = None):
     return _mesh((n,), ("data",))
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on
-    <=0.4.x — with replication checking off in both (mirrors the
-    test_distributed subprocess harnesses; flow-table placement is
-    explicit, so the checker adds nothing but version skew)."""
-    try:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+def flow_shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: flow-table
+    placement is explicit, so the checker adds nothing."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )

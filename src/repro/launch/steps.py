@@ -146,11 +146,7 @@ class Cell:
             out_shardings=self.out_shardings,
             donate_argnums=self.donate_argnums,
         )
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-        if set_mesh is not None:
-            with set_mesh(self.mesh):
-                return jitted.lower(*self.args)
-        with self.mesh:  # older jax: mesh context manager
+        with jax.sharding.set_mesh(self.mesh):
             return jitted.lower(*self.args)
 
 

@@ -42,9 +42,24 @@ from repro.serve.flow_engine import FlowEngineConfig
 
 ENGINE_KINDS = ("flow", "sharded", "elastic", "lm")
 
+#: where a deploy's flow-table budget came from when it named none: the
+#: device's HBM (see :func:`device_table_budget`)
+TABLE_BUDGET_STAGE = "flow-table-budget"
+
+#: share of a device's HBM a flow table may take when the deploy names no
+#: budget.  The rest is for weights and the hot-path step's temporaries,
+#: which scale with the table: compiled ahead of time for v5e at 4096 flows
+#: of the full arch, the fused step reserves ~1.3x the table and the
+#: per-round step ~0.7x; on the chip the fused run peaked at 1.6x the table
+#: in all.  At 40% both fit.
+TABLE_HBM_SHARE = 0.4
+
 #: deploy-scoped ledger stages refreshed (never duplicated) on re-deploys,
 #: so the program's audit trail always describes the ACTIVE deployment
-DEPLOY_STAGES = ("flow-table-sharding", "int-lowering", "admission-control")
+DEPLOY_STAGES = (
+    "flow-table-sharding", "int-lowering", "admission-control",
+    TABLE_BUDGET_STAGE,
+)
 
 
 # --------------------------------------------------------------------------
@@ -151,14 +166,47 @@ class Engine(Protocol):
 # ``from_program`` classmethods are shims over these)
 # --------------------------------------------------------------------------
 
+def device_table_budget() -> Optional[int]:
+    """Per-device flow-table budget from the HBM the device reports
+    (``memory_stats()["bytes_limit"]``), or None where the backend reports
+    no memory stats (the CPU)."""
+    import jax
+
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return None if limit is None else int(limit * TABLE_HBM_SHARE)
+
+
 def _site_fcfg(program, fcfg: FlowEngineConfig,
                backend: Optional[str]) -> FlowEngineConfig:
     """Resolve the deployment-site flow config against the program: backend
     precedence is spec override > fcfg.backend > program's pass selection;
-    the Eq. 39 horizon always comes from the program."""
+    the Eq. 39 horizon always comes from the program.  An unnamed table
+    budget (0) becomes the device's HBM share where the device reports its
+    memory: the engines' own default is a switch's 120 MB of SRAM, which the
+    full arch's ~1.6 MB/flow state cannot meet at any useful capacity, while
+    on a TPU host the table lives in HBM."""
     eff = backend if backend is not None else fcfg.backend
     eff = eff if eff is not None else program.backend
-    return dataclasses.replace(fcfg, backend=eff, horizon=program.horizon)
+    budget = fcfg.state_budget_bytes or device_table_budget() or 0
+    return dataclasses.replace(fcfg, backend=eff, horizon=program.horizon,
+                               state_budget_bytes=budget)
+
+
+def record_table_budget(program, requested: FlowEngineConfig, eng) -> None:
+    """Ledger row for a table budget taken from device HBM (``requested``
+    named none), recorded the way a waiver is: the substitution is part of
+    the deployment's audit trail."""
+    if requested.state_budget_bytes or not eng.fcfg.state_budget_bytes:
+        return
+    used = getattr(eng, "shard_state_bytes", eng.resident_state_bytes)
+    program.ledger.add(
+        TABLE_BUDGET_STAGE, "device-hbm-bytes", used=used(),
+        budget=eng.state_budget_bytes,
+        detail=f"per-device table budget = {TABLE_HBM_SHARE:g} x the "
+               f"device's memory_stats() bytes_limit, replacing the "
+               f"DataplaneSpec's switch SRAM; weights and the step's "
+               f"temporaries take the rest",
+    )
 
 
 def _reset_deploy_stages(program) -> None:
@@ -178,11 +226,12 @@ def build_flow_engine(program, fcfg: FlowEngineConfig = FlowEngineConfig(),
     kw = _engine_kwargs_from_program(
         program, backend=backend if backend is not None else fcfg.backend
     )
-    fcfg = _site_fcfg(program, fcfg, backend)
-    eng = FlowEngine(kw["ccfg"], kw["params"], kw["rules"], fcfg)
+    eng = FlowEngine(kw["ccfg"], kw["params"], kw["rules"],
+                     _site_fcfg(program, fcfg, backend))
     eng.program = program
     _reset_deploy_stages(program)
     program.ledger.entries.extend(eng._int_entries)
+    record_table_budget(program, fcfg, eng)
     return eng
 
 
@@ -203,15 +252,16 @@ def build_sharded_engine(program, fcfg: FlowEngineConfig = FlowEngineConfig(),
     kw = _engine_kwargs_from_program(
         program, backend=backend if backend is not None else fcfg.backend
     )
-    fcfg = _site_fcfg(program, fcfg, backend)
     eng = ShardedFlowEngine(
-        kw["ccfg"], kw["params"], kw["rules"], fcfg,
+        kw["ccfg"], kw["params"], kw["rules"],
+        _site_fcfg(program, fcfg, backend),
         mesh=mesh, num_shards=num_shards,
     )
     eng.program = program
     if record:
         _reset_deploy_stages(program)
         program.ledger.entries.extend(eng._int_entries)
+        record_table_budget(program, fcfg, eng)
         record_sharding_entry(program, eng)
         program.ledger.raise_if_over()
     return eng

@@ -67,6 +67,7 @@ from repro.serve.deploy import (
     _reset_deploy_stages,
     build_sharded_engine,
     record_sharding_entry,
+    record_table_budget,
 )
 from repro.serve.flow_engine import FlowEngineConfig
 from repro.serve.sharded_flow_engine import ShardedFlowEngine
@@ -312,6 +313,7 @@ class ElasticFlowService:
 
         _reset_deploy_stages(program)
         program.ledger.entries.extend(eng._int_entries)
+        record_table_budget(program, fcfg, eng)
         record_sharding_entry(program, eng, note="elastic")
         self._record_admission_entries()
         program.ledger.raise_if_over()

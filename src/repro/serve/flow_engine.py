@@ -181,6 +181,26 @@ def make_flow_step(
     return step
 
 
+def stage_impls(ccfg: C.ClassifierConfig, backend: str,
+                score_kernel: Optional[str] = None) -> Dict[str, str]:
+    """What each stage of the flow step runs, so a backend name never hides
+    an XLA fallback: ``decode`` (the backbone's per-token step) and
+    ``score`` (streaming scores + TCAM veto; ``score_kernel`` names the
+    Pallas backend of a fused ``flow_ingest`` score stage, if any)."""
+    from repro.core.chimera_attention import decode_kernel_gap
+
+    gap = decode_kernel_gap(ccfg.arch)
+    decode = (f"decode_step ({ccfg.arch.chimera.backend})" if gap is None
+              else f"xla ({gap})")
+    if backend == "int-emulation":
+        score = "int-emulation (int32 jnp)"
+    elif score_kernel is not None:
+        score = f"flow_ingest score kernel ({score_kernel})"
+    else:
+        score = "xla"
+    return {"decode": decode, "score": score}
+
+
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
@@ -541,6 +561,7 @@ class FlowEngine:
         # scores), so --fused composes with every backend.
         self._jit_fused = None
         self._staging: Dict[Tuple[int, int, int, int], Dict[str, np.ndarray]] = {}
+        self.stage_impls = stage_impls(self.ccfg, self.backend)
         if fcfg.fused:
             from repro.kernels import autotune
             from repro.kernels.dispatch import resolve
@@ -552,8 +573,12 @@ class FlowEngine:
             )
             tiles = None
             if fam_backend != "reference":
+                self.stage_impls = stage_impls(
+                    self.ccfg, self.backend, score_kernel=fam_backend
+                )
                 tiles = autotune.get_tiles(
-                    "flow_ingest", self.flow_ingest_dims(), fam_backend
+                    "flow_ingest", self.flow_ingest_dims(), fam_backend,
+                    spec=hardware_model.device_tpu_spec(),
                 )
             self._jit_fused = jax.jit(
                 resolve("flow_ingest", fam_backend)(

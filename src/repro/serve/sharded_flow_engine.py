@@ -50,6 +50,7 @@ from repro.core import hardware_model
 from repro.core import symbolic
 from repro.core.hardware_model import DEFAULT_DATAPLANE
 from repro.data.pipeline import arrival_rounds, flow_shard
+from repro.launch.mesh import flow_shard_map
 from repro.models import model as M
 from repro.serve.flow_engine import (
     FlowEngineConfig,
@@ -58,8 +59,44 @@ from repro.serve.flow_engine import (
     SwapRecord,
     make_flow_step,
     resolve_swap,
+    stage_impls,
 )
 from repro.train import classifier as C
+
+
+def make_sharded_step(ccfg: C.ClassifierConfig, n_slots: int, mesh,
+                      int_plan=None):
+    """The per-round flow step of every shard in one ``shard_map`` over the
+    mesh ``data`` axis: the single-device :func:`make_flow_step` applied to
+    each device's own table rows (leading shard axis), with params and
+    rules replicated.  Module-level so it can be compiled ahead of time for
+    a described mesh."""
+    step = make_flow_step(ccfg, n_slots, int_plan=int_plan)
+
+    def shard_step(params, rules, caches, positions, sig, hidden_sum,
+                   vetoed, idx, tokens, fresh):
+        # inside shard_map every table arg carries a leading shard axis
+        # of size 1 (this device's rows); params/rules arrive replicated
+        def sq(t):
+            return jax.tree_util.tree_map(lambda x: x[0], t)
+
+        caches, positions, sig, hidden_sum, vetoed, out = step(
+            params, rules, sq(caches), positions[0], sig[0],
+            hidden_sum[0], vetoed[0], idx[0], tokens[0], fresh[0],
+        )
+
+        def ex(t):
+            return jax.tree_util.tree_map(lambda x: x[None], t)
+
+        return (ex(caches), positions[None], sig[None], hidden_sum[None],
+                vetoed[None], ex(out))
+
+    return flow_shard_map(
+        shard_step, mesh,
+        in_specs=(P(), P(), P("data"), P("data"), P("data"), P("data"),
+                  P("data"), P("data"), P("data"), P("data")),
+        out_specs=(P("data"),) * 6,
+    )
 
 
 class ShardedFlowEngine:
@@ -83,7 +120,7 @@ class ShardedFlowEngine:
         num_shards: Optional[int] = None,
     ):
         from repro.kernels.dispatch import apply_kernel_backend
-        from repro.launch.mesh import make_flow_mesh, shard_map_compat
+        from repro.launch.mesh import make_flow_mesh
 
         if fcfg.fused:
             # the fused flow_ingest megakernel is a single-device launch;
@@ -117,6 +154,7 @@ class ShardedFlowEngine:
 
         arch, self.backend = apply_kernel_backend(ccfg.arch, fcfg.backend)
         self.ccfg = dataclasses.replace(ccfg, arch=arch)
+        self.stage_impls = stage_impls(self.ccfg, self.backend)
         self.fcfg = fcfg
         self.stats = FlowStats()
         self.swap_history: List[SwapRecord] = []
@@ -151,24 +189,27 @@ class ShardedFlowEngine:
 
         # per-shard slot-batched state (capacity real slots + one scratch
         # slot absorbing padding lanes), stacked on a leading shard axis
-        # that shard_map splits over 'data'
+        # that shard_map splits over 'data'.  Built by one jit whose output
+        # is row-sharded, so each device only ever allocates its own rows.
         self._n_slots = fcfg.capacity + 1
+        n = self._n_slots
+        W, d = self.ccfg.sig_words, arch.d_model
+        hs_dtype = jnp.int32 if self._int_plan is not None else jnp.float32
 
-        def shardwise(c):
-            return jax.device_put(
-                jnp.broadcast_to(c[None], (S,) + c.shape), self._row_sharded
+        def table():
+            rows = (
+                M.init_caches(arch, n, fcfg.max_flow_tokens, dtype=jnp.float32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.zeros((n, W), jnp.uint32),
+                jnp.zeros((n, d), hs_dtype),
+                jnp.zeros((n,), bool),
+            )
+            return jax.tree_util.tree_map(
+                lambda c: jnp.broadcast_to(c[None], (S,) + c.shape), rows
             )
 
-        caches = M.init_caches(
-            arch, self._n_slots, fcfg.max_flow_tokens, dtype=jnp.float32
-        )
-        self.caches = jax.tree_util.tree_map(shardwise, caches)
-        W, d = self.ccfg.sig_words, arch.d_model
-        self.positions = shardwise(jnp.zeros((self._n_slots,), jnp.int32))
-        self.sig = shardwise(jnp.zeros((self._n_slots, W), jnp.uint32))
-        hs_dtype = jnp.int32 if self._int_plan is not None else jnp.float32
-        self.hidden_sum = shardwise(jnp.zeros((self._n_slots, d), hs_dtype))
-        self.vetoed = shardwise(jnp.zeros((self._n_slots,), bool))
+        (self.caches, self.positions, self.sig, self.hidden_sum,
+         self.vetoed) = jax.jit(table, out_shardings=self._row_sharded)()
 
         # one host-side directory per shard: allocation, LRU and idle
         # eviction are shard-local (a flow only ever competes for slots
@@ -184,33 +225,10 @@ class ShardedFlowEngine:
             self._n_slots, self.per_flow_state_bytes(), budget
         )
 
-        step = make_flow_step(self.ccfg, self._n_slots, int_plan=self._int_plan)
-
-        def shard_step(params, rules, caches, positions, sig, hidden_sum,
-                       vetoed, idx, tokens, fresh):
-            # inside shard_map every table arg carries a leading shard axis
-            # of size 1 (this device's rows); params/rules arrive replicated
-            def sq(t):
-                return jax.tree_util.tree_map(lambda x: x[0], t)
-
-            caches, positions, sig, hidden_sum, vetoed, out = step(
-                params, rules, sq(caches), positions[0], sig[0],
-                hidden_sum[0], vetoed[0], idx[0], tokens[0], fresh[0],
-            )
-
-            def ex(t):
-                return jax.tree_util.tree_map(lambda x: x[None], t)
-
-            return (ex(caches), positions[None], sig[None], hidden_sum[None],
-                    vetoed[None], ex(out))
-
-        smap = shard_map_compat(
-            shard_step, mesh,
-            in_specs=(P(), P(), P("data"), P("data"), P("data"), P("data"),
-                      P("data"), P("data"), P("data"), P("data")),
-            out_specs=(P("data"),) * 6,
+        self._jit_step = jax.jit(
+            make_sharded_step(self.ccfg, self._n_slots, mesh, self._int_plan),
+            donate_argnums=(2, 3, 4, 5, 6),
         )
-        self._jit_step = jax.jit(smap, donate_argnums=(2, 3, 4, 5, 6))
 
     def jit_entry_points(self):
         """Named jitted hot-path callables, for the retrace sentry."""

@@ -109,15 +109,9 @@ SUBPROCESS_COMPRESSION = textwrap.dedent(
     def reduce_one(g, r):
         return compressed_mean(g, r, "data", bits=8)
 
-    if hasattr(jax, "shard_map"):  # newer jax
-        smap = jax.shard_map(reduce_one, mesh=mesh,
-            in_specs=(P("data"), P("data")), out_specs=(P(), P("data")),
-            check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(reduce_one, mesh=mesh,
-            in_specs=(P("data"), P("data")), out_specs=(P(), P("data")),
-            check_rep=False)
+    smap = jax.shard_map(reduce_one, mesh=mesh,
+        in_specs=(P("data"), P("data")), out_specs=(P(), P("data")),
+        check_vma=False)
     f = jax.jit(smap)
     key = jax.random.PRNGKey(0)
     g_local = jax.random.normal(key, (8, 64))  # one row per shard

@@ -157,7 +157,7 @@ class TestIntegerJaxpr:
 
     def test_audit_detects_float_ops(self):
         """The dtype scan is not vacuous: a float op anywhere — including
-        nested under pjit/scan — is flagged."""
+        nested under jit/scan — is flagged."""
         jx = jax.make_jaxpr(
             lambda x: (x.astype(jnp.float32) * 0.5).astype(jnp.int32)
         )(jax.ShapeDtypeStruct((4,), jnp.int32))

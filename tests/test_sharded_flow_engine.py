@@ -303,7 +303,7 @@ SUBPROCESS_EQUIVALENCE = textwrap.dedent(
     from repro.configs import smoke_config
     from repro.data.pipeline import FlowScenario
     from repro.serve.deploy import DeploySpec
-from repro.serve.flow_engine import FlowEngine, FlowEngineConfig
+    from repro.serve.flow_engine import FlowEngine, FlowEngineConfig
     from repro.serve.sharded_flow_engine import ShardedFlowEngine
     from repro.train import classifier as C
 

@@ -1,0 +1,148 @@
+"""Compile the main path's kernels for a described TPU v5e, at the published
+widths of ``configs/chimera_dataplane.py`` (d_model 256, 4 heads of 64,
+m=256, d_v 64, L=64, 24 signature words).
+
+Nothing here runs: each test compiles for a chip that is described and not
+attached, so Mosaic refuses here what it would refuse on the chip.  The
+topology is described inside a module-scoped fixture (never on import), and
+the persistent compilation cache is off around these compiles, since an
+entry written for a described chip cannot be read back without one.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the widths the tests compile at (chimera-dataplane, full width)
+BH, GQ, D, M, DV, L = 1024, 1, 64, 256, 64, 64
+T = 1024
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip; skips where no topology can be described."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def full_width():
+    """The served classifier config at full width, with its compiled
+    signature layout, plus abstract params and rules."""
+    from repro.compile.passes import required_sig_words
+    from repro.core.symbolic import RuleSet
+    from repro.launch.flow_serve import classifier_config
+    from repro.train import classifier as C
+
+    ccfg = classifier_config()
+    ccfg = dataclasses.replace(ccfg, sig_words=required_sig_words(
+        ccfg.arch.vocab_size, ccfg.marker_base
+    ))
+    params = jax.eval_shape(
+        lambda k: C.init_classifier(ccfg, k)[0], jax.random.PRNGKey(0)
+    )
+    W = ccfg.sig_words
+    rules = RuleSet(
+        values=jax.ShapeDtypeStruct((1, W), jnp.uint32),
+        masks=jax.ShapeDtypeStruct((1, W), jnp.uint32),
+        weights=jax.ShapeDtypeStruct((1,), jnp.float32),
+        hard=jax.ShapeDtypeStruct((1,), bool),
+    )
+    return ccfg, params, rules
+
+
+def test_decode_step_kernel_compiles(chip):
+    from repro.kernels.decode_step.kernel import decode_step_pallas
+
+    f32 = jnp.float32
+    s = lambda *shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (s(BH, GQ, D), s(BH, D), s(BH, DV), s(BH, GQ, M), s(BH, L, M),
+            s(BH, L, D), s(BH, L, DV), s(BH, M, DV), s(BH, M),
+            s(BH, dt=jnp.int32))
+    text = decode_step_pallas.lower(*args, chunk_size=L).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_chimera_attention_kernel_compiles(chip):
+    from repro.kernels.chimera_attention.kernel import chimera_attention_pallas
+
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    text = _compiled_text(
+        lambda q, k, v, pq, pk: chimera_attention_pallas(
+            q, k, v, pq, pk, chunk_size=L
+        ),
+        s(4, GQ, T, D), s(4, T, D), s(4, T, DV), s(4, GQ, T, M), s(4, T, M),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_flow_ingest_score_stage_compiles(chip, full_width):
+    from repro.kernels.flow_ingest.kernel import flow_ingest_scores_pallas
+
+    ccfg, params, rules = full_width
+    lanes, d, W = 256, ccfg.arch.d_model, ccfg.sig_words
+    text = _compiled_text(
+        lambda p, r, pooled, sig, sticky: flow_ingest_scores_pallas(
+            ccfg, p, r, pooled, sig, sticky
+        ),
+        _shapes(params, chip), _shapes(rules, chip),
+        jax.ShapeDtypeStruct((lanes, d), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct((lanes, W), jnp.uint32, sharding=chip),
+        jax.ShapeDtypeStruct((lanes,), bool, sharding=chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_fused_pallas_tpu_ingest_holds_a_kernel(chip, full_width):
+    """The fused ``pallas-tpu`` flow step at full width (small capacity):
+    its score stage must compile to a Mosaic kernel, not to XLA."""
+    from repro.kernels.dispatch import apply_kernel_backend, resolve
+    from repro.models import model as M_
+
+    ccfg, params, rules = full_width
+    arch, _ = apply_kernel_backend(ccfg.arch, "pallas-tpu")
+    ccfg = dataclasses.replace(ccfg, arch=arch)
+    n_slots, width, chunks, pkt_len = 65, 16, 8, 16
+    caches = jax.eval_shape(
+        lambda: M_.init_caches(arch, n_slots, 1024, dtype=jnp.float32)
+    )
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    fused = resolve("flow_ingest", "pallas-tpu")(ccfg, n_slots)
+    text = _compiled_text(
+        fused, _shapes(params, chip), _shapes(rules, chip),
+        _shapes(caches, chip), s((n_slots,), jnp.int32),
+        s((n_slots, ccfg.sig_words), jnp.uint32),
+        s((n_slots, arch.d_model), jnp.float32), s((n_slots,), bool),
+        s((chunks, width), jnp.int32), s((chunks, width, pkt_len), jnp.int32),
+        s((chunks, width), bool), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
