@@ -55,6 +55,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import Checkpointer
 from repro.core import hardware_model
@@ -449,42 +450,43 @@ class ElasticFlowService:
             )
             self.reshard_history.append(rec)
             return rec
-        self._resharding = True  # quiesce: no ingest during the install
-        try:
-            fids = np.array(sorted(self._all_fids()), np.int64)
-            moved = int(reshard_moves(fids, old_S, num_shards).sum())
-            snap = snapshot_flow_state(eng)
-            if self._ckpt is not None:
-                # reshard snapshots ride the same checkpoint stream (they
-                # are the freshest restore point a recovery could want)
-                self._persist_snapshot(snap, kind=f"reshard->{num_shards}")
-            target = self._engine_for(num_shards)
+        with TraceAnnotation("flow.reshard", tick=eng._tick, shards=num_shards):
+            self._resharding = True  # quiesce: no ingest during the install
+            try:
+                fids = np.array(sorted(self._all_fids()), np.int64)
+                moved = int(reshard_moves(fids, old_S, num_shards).sum())
+                snap = snapshot_flow_state(eng)
+                if self._ckpt is not None:
+                    # reshard snapshots ride the same checkpoint stream (they
+                    # are the freshest restore point a recovery could want)
+                    self._persist_snapshot(snap, kind=f"reshard->{num_shards}")
+                target = self._engine_for(num_shards)
 
-            def _install():
-                self._carry_tables(eng, target)
-                install_flow_state(target, snap, tick=eng._tick)
-                return target.positions
+                def _install():
+                    self._carry_tables(eng, target)
+                    install_flow_state(target, snap, tick=eng._tick)
+                    return target.positions
 
-            dt = measure_install_time(_install)
-            ok = (
-                hardware_model.install_time_ok(dt, t_cp) if t_cp else True
-            )
-            rec = ReshardRecord(
-                tick=eng._tick, old_shards=old_S, new_shards=num_shards,
-                reason=reason, migrated_flows=int(len(fids)),
-                moved_flows=moved, install_s=dt, t_cp_s=t_cp, churn_ok=ok,
-            )
-            if ok:
-                self._commit(target)
-            else:
-                rec.rolled_back = True
-                rec.error = (
-                    f"reshard install {dt:.6f}s exceeded t_cp {t_cp:.6f}s "
-                    f"(Eq. 18); rolled back — old topology keeps serving"
+                dt = measure_install_time(_install)
+                ok = (
+                    hardware_model.install_time_ok(dt, t_cp) if t_cp else True
                 )
-                target.reset()  # discard the provisional rows
-        finally:
-            self._resharding = False
+                rec = ReshardRecord(
+                    tick=eng._tick, old_shards=old_S, new_shards=num_shards,
+                    reason=reason, migrated_flows=int(len(fids)),
+                    moved_flows=moved, install_s=dt, t_cp_s=t_cp, churn_ok=ok,
+                )
+                if ok:
+                    self._commit(target)
+                else:
+                    rec.rolled_back = True
+                    rec.error = (
+                        f"reshard install {dt:.6f}s exceeded t_cp {t_cp:.6f}s "
+                        f"(Eq. 18); rolled back — old topology keeps serving"
+                    )
+                    target.reset()  # discard the provisional rows
+            finally:
+                self._resharding = False
         self.reshard_history.append(rec)
         return rec
 

@@ -24,6 +24,19 @@ State is bounded twice over: per-flow by construction (Chimera decode state
 never grows with flow length) and table-wide by an explicit byte budget
 (:func:`repro.core.hardware_model.check_flow_table_budget`) with LRU and
 idle eviction keeping the resident set inside ``capacity``.
+
+Host spans: every ``ingest`` call records ``jax.profiler.TraceAnnotation``
+spans at its layer boundaries, each with the call's tick as its ``call``
+stat — ``flow.resolve`` (tick, LRU touches, idle sweep, slot assignment),
+``flow.pack`` (arrival rounds into launch buffers), ``flow.launch`` (the
+host-to-device puts of one launch) and, inside it, ``flow.dispatch`` (the
+jitted call, which blocks while the runtime already holds its limit of
+executions in flight), ``flow.finalize`` (reading the answers back and
+unpacking them per packet) and, inside it, ``flow.wait`` (the host blocked
+on the device's outputs).  ``swap_tables`` records ``flow.swap``.  The
+spans land in the profiler's trace beside the device's ops when
+``jax.profiler.trace`` is on, and cost about a microsecond each when it is
+off.  The sharded engine records the same names.
 """
 
 from __future__ import annotations
@@ -35,6 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import hardware_model
 from repro.core import symbolic
@@ -62,7 +76,6 @@ class FlowEngineConfig:
 @dataclasses.dataclass
 class FlowStats:
     packets: int = 0
-    tokens: int = 0
     ticks: int = 0
     rounds: int = 0
     flows_created: int = 0
@@ -313,45 +326,51 @@ class _PendingIngest:
     :meth:`FlowEngine._dispatch_fused` returns one of these *before*
     blocking on device results, so the async pipeline can pack and dispatch
     the next batch while the device chews on this one.  ``finalize()``
-    blocks (the first host read of the output arrays) and unpacks the
+    blocks on each launch's outputs (the ``flow.wait`` span) and unpacks the
     per-chunk score stacks into the per-packet dict ``ingest`` returns.
+    ``call`` is the engine tick of the ingest call, the ``call`` stat of
+    every span the call records.
     """
 
-    def __init__(self, engine, flow_ids, n_packets: int, launches):
+    def __init__(self, engine, flow_ids, n_packets: int, launches, call: int):
         self.engine = engine
         self.flow_ids = flow_ids
         self.n_packets = n_packets
         self.launches = launches  # [(outs pytree, [chunk packet-index arrays])]
+        self.call = call
         self._result: Optional[Dict[str, np.ndarray]] = None
 
     def finalize(self) -> Dict[str, np.ndarray]:
         if self._result is not None:
             return self._result
-        P = self.n_packets
-        out = {
-            "flow_ids": self.flow_ids,
-            "trust": np.empty((P,), np.float32),
-            "vetoed": np.empty((P,), bool),
-            "pred": np.empty((P,), np.int32),
-            "s_nn": np.empty((P,), np.float32),
-            "s_sym": np.empty((P,), np.float32),
-            "sig": np.zeros((P, self.engine.ccfg.sig_words), np.uint32),
-        }
-        for outs, chunks in self.launches:
-            trust = np.asarray(outs["trust"], np.float32)
-            hard = np.asarray(outs["hard_hit"])
-            logits = np.asarray(outs["class_logits"])
-            s_nn = np.asarray(outs["s_nn"], np.float32)
-            s_sym = np.asarray(outs["s_sym"], np.float32)
-            sig = np.asarray(outs["sig"])
-            for j, ch in enumerate(chunks):
-                n = len(ch)
-                out["trust"][ch] = trust[j, :n]
-                out["vetoed"][ch] = hard[j, :n]
-                out["pred"][ch] = np.argmax(logits[j, :n], -1).astype(np.int32)
-                out["s_nn"][ch] = s_nn[j, :n]
-                out["s_sym"][ch] = s_sym[j, :n]
-                out["sig"][ch] = sig[j, :n]
+        with TraceAnnotation("flow.finalize", call=self.call):
+            P = self.n_packets
+            out = {
+                "flow_ids": self.flow_ids,
+                "trust": np.empty((P,), np.float32),
+                "vetoed": np.empty((P,), bool),
+                "pred": np.empty((P,), np.int32),
+                "s_nn": np.empty((P,), np.float32),
+                "s_sym": np.empty((P,), np.float32),
+                "sig": np.zeros((P, self.engine.ccfg.sig_words), np.uint32),
+            }
+            for outs, chunks in self.launches:
+                with TraceAnnotation("flow.wait", call=self.call):
+                    jax.block_until_ready(outs)
+                trust = np.asarray(outs["trust"], np.float32)
+                hard = np.asarray(outs["hard_hit"])
+                logits = np.asarray(outs["class_logits"])
+                s_nn = np.asarray(outs["s_nn"], np.float32)
+                s_sym = np.asarray(outs["s_sym"], np.float32)
+                sig = np.asarray(outs["sig"])
+                for j, ch in enumerate(chunks):
+                    n = len(ch)
+                    out["trust"][ch] = trust[j, :n]
+                    out["vetoed"][ch] = hard[j, :n]
+                    out["pred"][ch] = np.argmax(logits[j, :n], -1).astype(np.int32)
+                    out["s_nn"][ch] = s_nn[j, :n]
+                    out["s_sym"][ch] = s_sym[j, :n]
+                    out["sig"][ch] = sig[j, :n]
         self._result = out
         return out
 
@@ -780,23 +799,23 @@ class FlowEngine:
         observe the identical eviction sequence."""
         self._tick += 1
         self.stats.ticks += 1
+        with TraceAnnotation("flow.resolve", call=self._tick):
+            # touch every already-resident flow in this batch BEFORE the idle
+            # sweep and any allocation: eviction victims (idle or LRU) must come
+            # from flows with no packets pending here, or a resident (possibly
+            # vetoed) flow could lose its state on the very tick it transmits.
+            # Only when the batch itself holds more distinct flows than the
+            # table has entries is evicting an in-batch flow unavoidable (state
+            # loss on eviction is inherent to a bounded table).
+            for fid in set(flow_ids.tolist()):
+                self.table.touch(fid, self._tick)
+            self.evict_idle()
 
-        # touch every already-resident flow in this batch BEFORE the idle
-        # sweep and any allocation: eviction victims (idle or LRU) must come
-        # from flows with no packets pending here, or a resident (possibly
-        # vetoed) flow could lose its state on the very tick it transmits.
-        # Only when the batch itself holds more distinct flows than the
-        # table has entries is evicting an in-batch flow unavoidable (state
-        # loss on eviction is inherent to a bounded table).
-        for fid in set(flow_ids.tolist()):
-            self.table.touch(fid, self._tick)
-        self.evict_idle()
-
-        P = len(flow_ids)
-        slots = np.empty((P,), np.int32)
-        fresh = np.zeros((P,), bool)
-        for i, fid in enumerate(flow_ids.tolist()):
-            slots[i], fresh[i] = self._slot_for(fid)
+            P = len(flow_ids)
+            slots = np.empty((P,), np.int32)
+            fresh = np.zeros((P,), bool)
+            for i, fid in enumerate(flow_ids.tolist()):
+                slots[i], fresh[i] = self._slot_for(fid)
         return slots, fresh
 
     def _ingest_rounds(
@@ -815,34 +834,43 @@ class FlowEngine:
 
         lanes = self.fcfg.lanes
         scratch = self.fcfg.capacity
-        for round_lanes in arrival_rounds(slots.tolist()):
+        call = self._tick
+        with TraceAnnotation("flow.pack", call=call):
+            rounds = arrival_rounds(slots.tolist())
+        for round_lanes in rounds:
             for c0 in range(0, len(round_lanes), lanes):
-                chunk = round_lanes[c0 : c0 + lanes]
-                idx = np.full((lanes,), scratch, np.int32)
-                tok = np.zeros((lanes, pkt_len), np.int32)
-                fr = np.zeros((lanes,), bool)
-                n = len(chunk)
-                idx[:n] = slots[chunk]
-                tok[:n] = tokens[chunk]
-                fr[:n] = fresh[chunk]
-                (self.caches, self.positions, self.sig, self.hidden_sum,
-                 self.vetoed, out) = self._jit_step(
-                    self.params, self._step_rules(), self.caches, self.positions,
-                    self.sig, self.hidden_sum, self.vetoed,
-                    jnp.asarray(idx), jnp.asarray(tok), jnp.asarray(fr),
-                )
+                with TraceAnnotation("flow.pack", call=call):
+                    chunk = round_lanes[c0 : c0 + lanes]
+                    idx = np.full((lanes,), scratch, np.int32)
+                    tok = np.zeros((lanes, pkt_len), np.int32)
+                    fr = np.zeros((lanes,), bool)
+                    n = len(chunk)
+                    idx[:n] = slots[chunk]
+                    tok[:n] = tokens[chunk]
+                    fr[:n] = fresh[chunk]
+                with TraceAnnotation("flow.launch", call=call, width=lanes, chunks=1):
+                    args = (jnp.asarray(idx), jnp.asarray(tok), jnp.asarray(fr))
+                    with TraceAnnotation("flow.dispatch", call=call):
+                        (self.caches, self.positions, self.sig, self.hidden_sum,
+                         self.vetoed, out) = self._jit_step(
+                            self.params, self._step_rules(), self.caches,
+                            self.positions, self.sig, self.hidden_sum,
+                            self.vetoed, *args,
+                        )
                 self.stats.rounds += 1
-                lanes_idx = np.asarray(chunk, np.intp)
-                out_trust[lanes_idx] = np.asarray(out["trust"], np.float32)[:n]
-                out_veto[lanes_idx] = np.asarray(out["hard_hit"])[:n]
-                out_pred[lanes_idx] = np.asarray(
-                    jnp.argmax(out["class_logits"], -1), np.int32
-                )[:n]
-                out_s_nn[lanes_idx] = np.asarray(out["s_nn"], np.float32)[:n]
-                out_s_sym[lanes_idx] = np.asarray(out["s_sym"], np.float32)[:n]
-                out_sig[lanes_idx] = np.asarray(out["sig"])[:n]
+                with TraceAnnotation("flow.finalize", call=call):
+                    with TraceAnnotation("flow.wait", call=call):
+                        jax.block_until_ready(out)
+                    lanes_idx = np.asarray(chunk, np.intp)
+                    out_trust[lanes_idx] = np.asarray(out["trust"], np.float32)[:n]
+                    out_veto[lanes_idx] = np.asarray(out["hard_hit"])[:n]
+                    out_pred[lanes_idx] = np.asarray(
+                        jnp.argmax(out["class_logits"], -1), np.int32
+                    )[:n]
+                    out_s_nn[lanes_idx] = np.asarray(out["s_nn"], np.float32)[:n]
+                    out_s_sym[lanes_idx] = np.asarray(out["s_sym"], np.float32)[:n]
+                    out_sig[lanes_idx] = np.asarray(out["sig"])[:n]
         self.stats.packets += P
-        self.stats.tokens += P * pkt_len
         return {
             "flow_ids": flow_ids,
             "trust": out_trust,
@@ -892,42 +920,47 @@ class FlowEngine:
         # the input transfers) has completed before a ring slot's pool is
         # reused, so cross-batch reuse stays race-free.
         uses: Dict[Tuple[int, int, int], int] = {}
-        for w, chunks in pack_width_groups(
-            slots, lanes, self.fcfg.min_chunk_lanes
-        ):
-            c_pad = max(_CHUNK_FLOOR, _next_pow2(len(chunks)))
-            shape = (w, c_pad, pkt_len)
-            occ = uses.get(shape, 0)
-            uses[shape] = occ + 1
-            key = (w, c_pad, pkt_len, occ)
-            buf = pool.get(key)
-            if buf is None:
-                buf = pool[key] = {
-                    "idx": np.empty((c_pad, w), np.int32),
-                    "tok": np.empty((c_pad, w, pkt_len), np.int32),
-                    "fr": np.empty((c_pad, w), bool),
-                }
-            idx, tok, fr = buf["idx"], buf["tok"], buf["fr"]
-            idx.fill(scratch)
-            tok.fill(0)
-            fr.fill(False)
-            for j, ch in enumerate(chunks):
-                n = len(ch)
-                idx[j, :n] = slots[ch]
-                tok[j, :n] = tokens[ch]
-                fr[j, :n] = fresh[ch]
-            (self.caches, self.positions, self.sig, self.hidden_sum,
-             self.vetoed, outs) = self._jit_fused(
-                self.params, self._step_rules(), self.caches, self.positions,
-                self.sig, self.hidden_sum, self.vetoed,
-                jnp.asarray(idx), jnp.asarray(tok), jnp.asarray(fr),
-                jnp.int32(len(chunks)),
-            )
+        call = self._tick
+        with TraceAnnotation("flow.pack", call=call):
+            groups = pack_width_groups(slots, lanes, self.fcfg.min_chunk_lanes)
+        for w, chunks in groups:
+            with TraceAnnotation("flow.pack", call=call, width=w,
+                                 chunks=len(chunks)):
+                c_pad = max(_CHUNK_FLOOR, _next_pow2(len(chunks)))
+                shape = (w, c_pad, pkt_len)
+                occ = uses.get(shape, 0)
+                uses[shape] = occ + 1
+                key = (w, c_pad, pkt_len, occ)
+                buf = pool.get(key)
+                if buf is None:
+                    buf = pool[key] = {
+                        "idx": np.empty((c_pad, w), np.int32),
+                        "tok": np.empty((c_pad, w, pkt_len), np.int32),
+                        "fr": np.empty((c_pad, w), bool),
+                    }
+                idx, tok, fr = buf["idx"], buf["tok"], buf["fr"]
+                idx.fill(scratch)
+                tok.fill(0)
+                fr.fill(False)
+                for j, ch in enumerate(chunks):
+                    n = len(ch)
+                    idx[j, :n] = slots[ch]
+                    tok[j, :n] = tokens[ch]
+                    fr[j, :n] = fresh[ch]
+            with TraceAnnotation("flow.launch", call=call, width=w,
+                                 chunks=len(chunks)):
+                args = (jnp.asarray(idx), jnp.asarray(tok), jnp.asarray(fr))
+                with TraceAnnotation("flow.dispatch", call=call):
+                    (self.caches, self.positions, self.sig, self.hidden_sum,
+                     self.vetoed, outs) = self._jit_fused(
+                        self.params, self._step_rules(), self.caches,
+                        self.positions, self.sig, self.hidden_sum,
+                        self.vetoed, *args, jnp.int32(len(chunks)),
+                    )
             self.stats.rounds += len(chunks)
             launches.append((outs, chunks))
         self.stats.packets += P
-        self.stats.tokens += P * pkt_len
-        return _PendingIngest(self, flow_ids, P, launches)
+        return _PendingIngest(self, flow_ids, P, launches, call)
 
     # ------------------------------------------------------------------
     # per-flow snapshot
@@ -1008,7 +1041,8 @@ class FlowEngine:
                 }
             return installed["rules"]
 
-        dt = measure_install_time(_install)
+        with TraceAnnotation("flow.swap", tick=self._tick):
+            dt = measure_install_time(_install)
         self.rules = installed["rules"]
         if "tables" in installed:
             self._int_tables = installed["tables"]

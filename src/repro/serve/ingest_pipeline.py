@@ -19,7 +19,13 @@ arrival order (the flow directory is host state, mutated synchronously),
 and the device launches are enqueued in order on one stream, so the fused
 path remains bit-identical to synchronous ingest.  The ring only bounds
 how far the *host* runs ahead; ``submit`` applies backpressure by
-finalizing the batch that last used the slot it is about to reuse.
+finalizing the batch that last used the slot it is about to reuse (in a
+profiler trace, the ``flow.wait`` span inside that batch's ``flow.finalize``).
+The TPU runtime bounds it too: it holds at most 32 executions in flight
+(two per fused launch, the step and its chunk count), and a dispatch past
+that blocks until the device finishes one (the ``flow.dispatch`` span).
+With more launches in the ring than that, the host waits there, and the
+batch that ``submit`` finalizes is already done.
 
     pipe = AsyncIngestPipeline(engine)         # engine built with fused=True
     for batch in scenario:

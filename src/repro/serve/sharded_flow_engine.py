@@ -43,6 +43,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -363,40 +364,43 @@ class ShardedFlowEngine:
         assert flow_ids.shape == (Pk,), (flow_ids.shape, Pk)
         self._tick += 1
         self.stats.ticks += 1
-        owners = flow_shard(flow_ids, self.num_shards)
+        call = self._tick
+        with TraceAnnotation("flow.resolve", call=call):
+            owners = flow_shard(flow_ids, self.num_shards)
 
-        # touch resident flows in this batch BEFORE the idle sweep and any
-        # allocation (same victim-selection contract as the single-device
-        # engine: flows with packets pending here are not eviction victims
-        # unless their shard is over-subscribed within this very batch)
-        for fid, own in zip(flow_ids.tolist(), owners.tolist()):
-            self.tables[own].touch(fid, self._tick)
-        self.evict_idle()
+            # touch resident flows in this batch BEFORE the idle sweep and any
+            # allocation (same victim-selection contract as the single-device
+            # engine: flows with packets pending here are not eviction victims
+            # unless their shard is over-subscribed within this very batch)
+            for fid, own in zip(flow_ids.tolist(), owners.tolist()):
+                self.tables[own].touch(fid, self._tick)
+            self.evict_idle()
 
-        slots = np.empty((Pk,), np.int32)
-        fresh = np.zeros((Pk,), bool)
-        for i, (fid, own) in enumerate(zip(flow_ids.tolist(), owners.tolist())):
-            slot, fr, evicted = self.tables[own].slot_for(fid, self._tick)
-            slots[i], fresh[i] = slot, fr
-            if fr:
-                self.stats.flows_created += 1
-            if evicted:
-                self.stats.flows_evicted_lru += 1
+            slots = np.empty((Pk,), np.int32)
+            fresh = np.zeros((Pk,), bool)
+            for i, (fid, own) in enumerate(zip(flow_ids.tolist(), owners.tolist())):
+                slot, fr, evicted = self.tables[own].slot_for(fid, self._tick)
+                slots[i], fresh[i] = slot, fr
+                if fr:
+                    self.stats.flows_created += 1
+                if evicted:
+                    self.stats.flows_evicted_lru += 1
 
         # shard-local arrival rounds, flattened to fixed-width lane chunks;
         # chunk k of every shard rides the same device launch
         lanes = self.fcfg.lanes
         scratch = self.fcfg.capacity
-        per_shard_chunks: List[List[np.ndarray]] = []
-        for s in range(self.num_shards):
-            pkt_idx = np.nonzero(owners == s)[0]
-            chunks: List[np.ndarray] = []
-            for round_lanes in arrival_rounds(slots[pkt_idx].tolist()):
-                sel = pkt_idx[round_lanes]
-                for c0 in range(0, len(sel), lanes):
-                    chunks.append(sel[c0 : c0 + lanes])
-            per_shard_chunks.append(chunks)
-        n_steps = max((len(c) for c in per_shard_chunks), default=0)
+        with TraceAnnotation("flow.pack", call=call):
+            per_shard_chunks: List[List[np.ndarray]] = []
+            for s in range(self.num_shards):
+                pkt_idx = np.nonzero(owners == s)[0]
+                chunks: List[np.ndarray] = []
+                for round_lanes in arrival_rounds(slots[pkt_idx].tolist()):
+                    sel = pkt_idx[round_lanes]
+                    for c0 in range(0, len(sel), lanes):
+                        chunks.append(sel[c0 : c0 + lanes])
+                per_shard_chunks.append(chunks)
+            n_steps = max((len(c) for c in per_shard_chunks), default=0)
 
         out_trust = np.empty((Pk,), np.float32)
         out_veto = np.empty((Pk,), bool)
@@ -406,47 +410,52 @@ class ShardedFlowEngine:
         out_sig = np.zeros((Pk, self.ccfg.sig_words), np.uint32)
 
         for k in range(n_steps):
-            idx = np.full((self.num_shards, lanes), scratch, np.int32)
-            tok = np.zeros((self.num_shards, lanes, pkt_len), np.int32)
-            fr = np.zeros((self.num_shards, lanes), bool)
-            chunk_of: List[Optional[np.ndarray]] = [None] * self.num_shards
-            for s, chunks in enumerate(per_shard_chunks):
-                if k < len(chunks):
-                    sel = chunks[k]
-                    n = len(sel)
-                    idx[s, :n] = slots[sel]
-                    tok[s, :n] = tokens[sel]
-                    fr[s, :n] = fresh[sel]
-                    chunk_of[s] = sel
-            (self.caches, self.positions, self.sig, self.hidden_sum,
-             self.vetoed, out) = self._jit_step(
-                self.params, self._step_rules(), self.caches, self.positions,
-                self.sig, self.hidden_sum, self.vetoed,
-                jax.device_put(idx, self._row_sharded),
-                jax.device_put(tok, self._row_sharded),
-                jax.device_put(fr, self._row_sharded),
-            )
+            with TraceAnnotation("flow.pack", call=call):
+                idx = np.full((self.num_shards, lanes), scratch, np.int32)
+                tok = np.zeros((self.num_shards, lanes, pkt_len), np.int32)
+                fr = np.zeros((self.num_shards, lanes), bool)
+                chunk_of: List[Optional[np.ndarray]] = [None] * self.num_shards
+                for s, chunks in enumerate(per_shard_chunks):
+                    if k < len(chunks):
+                        sel = chunks[k]
+                        n = len(sel)
+                        idx[s, :n] = slots[sel]
+                        tok[s, :n] = tokens[sel]
+                        fr[s, :n] = fresh[sel]
+                        chunk_of[s] = sel
+            with TraceAnnotation("flow.launch", call=call, width=lanes, chunks=1):
+                args = tuple(jax.device_put(a, self._row_sharded)
+                             for a in (idx, tok, fr))
+                with TraceAnnotation("flow.dispatch", call=call):
+                    (self.caches, self.positions, self.sig, self.hidden_sum,
+                     self.vetoed, out) = self._jit_step(
+                        self.params, self._step_rules(), self.caches,
+                        self.positions, self.sig, self.hidden_sum,
+                        self.vetoed, *args,
+                    )
             self.stats.rounds += 1
             # ONE stacked gather per round across every shard (no per-shard
             # host round trips)
-            trust = np.asarray(out["trust"], np.float32)
-            hard = np.asarray(out["hard_hit"])
-            pred = np.asarray(jnp.argmax(out["class_logits"], -1), np.int32)
-            s_nn = np.asarray(out["s_nn"], np.float32)
-            s_sym = np.asarray(out["s_sym"], np.float32)
-            sig_rows = np.asarray(out["sig"])
-            for s, sel in enumerate(chunk_of):
-                if sel is None:
-                    continue
-                n = len(sel)
-                out_trust[sel] = trust[s, :n]
-                out_veto[sel] = hard[s, :n]
-                out_pred[sel] = pred[s, :n]
-                out_s_nn[sel] = s_nn[s, :n]
-                out_s_sym[sel] = s_sym[s, :n]
-                out_sig[sel] = sig_rows[s, :n]
+            with TraceAnnotation("flow.finalize", call=call):
+                with TraceAnnotation("flow.wait", call=call):
+                    jax.block_until_ready(out)
+                trust = np.asarray(out["trust"], np.float32)
+                hard = np.asarray(out["hard_hit"])
+                pred = np.asarray(jnp.argmax(out["class_logits"], -1), np.int32)
+                s_nn = np.asarray(out["s_nn"], np.float32)
+                s_sym = np.asarray(out["s_sym"], np.float32)
+                sig_rows = np.asarray(out["sig"])
+                for s, sel in enumerate(chunk_of):
+                    if sel is None:
+                        continue
+                    n = len(sel)
+                    out_trust[sel] = trust[s, :n]
+                    out_veto[sel] = hard[s, :n]
+                    out_pred[sel] = pred[s, :n]
+                    out_s_nn[sel] = s_nn[s, :n]
+                    out_s_sym[sel] = s_sym[s, :n]
+                    out_sig[sel] = sig_rows[s, :n]
         self.stats.packets += Pk
-        self.stats.tokens += Pk * pkt_len
         return {
             "flow_ids": flow_ids,
             "trust": out_trust,
@@ -540,7 +549,8 @@ class ShardedFlowEngine:
                 }
             return installed["rules"]
 
-        dt = measure_install_time(_install)
+        with TraceAnnotation("flow.swap", tick=self._tick):
+            dt = measure_install_time(_install)
         self.rules = installed["rules"]
         if "tables" in installed:
             self._int_tables = installed["tables"]

@@ -147,8 +147,8 @@ def _assert_replay_identical(classifier, num_shards, kind="rule-violating",
         assert single.flow_scores(fid) == sharded.flow_scores(fid), fid
     s1, s2 = single.stats, sharded.stats
     assert s1.flows_evicted == s2.flows_evicted == 0  # precondition held
-    assert (s1.packets, s1.tokens, s1.flows_created) == (
-        s2.packets, s2.tokens, s2.flows_created)
+    assert (s1.packets, s1.ticks, s1.flows_created) == (
+        s2.packets, s2.ticks, s2.flows_created)
     return single, sharded
 
 
