@@ -124,9 +124,9 @@ def snapshot_flow_state(eng: ShardedFlowEngine) -> Dict[str, Any]:
 
     def cache_rows(leaf):
         h = np.asarray(leaf)
-        if h.ndim >= 3 and h.shape[2] == n_slots:
-            # sharded slotted leaf (S, groups, n_slots, ...): rows (n, groups, ...)
-            return h[s_idx, :, sl_idx]
+        if h.ndim >= 3 and h.shape[1] == n_slots:
+            # sharded slotted leaf (S, n_slots, groups, ...): rows (n, groups, ...)
+            return h[s_idx, sl_idx]
         # non-slotted leaves are never written back by the flow step (see
         # make_flow_step's put()) — every shard still holds the init value,
         # so a zero-length placeholder keeps the tree structure without
@@ -240,7 +240,7 @@ def install_flow_state(
         if rows.ndim == 1 and rows.shape[0] == 0:
             return leaf  # non-slotted constant: keep the engine's copy
         h = np.zeros(leaf.shape, leaf.dtype)
-        h[s_idx, :, sl_idx] = rows
+        h[s_idx, sl_idx] = rows
         return jax.device_put(jnp.asarray(h), eng._row_sharded)
 
     new_state = (

@@ -110,7 +110,16 @@ def make_flow_step(
     freshly-allocated slots), scan the packet tokens through
     :func:`repro.models.model.decode_hidden_step`, accumulate the packed
     marker signature, score via :func:`repro.train.classifier
-    .streaming_scores`, scatter the rows back.  Module-level so
+    .streaming_scores`, scatter the rows back.
+
+    The table is slot-major: every slotted cache leaf is stored
+    ``(slots, layers, ...)`` (:func:`init_flow_caches`), like ``positions``,
+    ``sig``, ``hidden_sum`` and ``vetoed``.  The gather ``c[idx]`` and the
+    scatter ``c.at[idx].set`` then index the major axis of a row-major
+    array, so the fused loop carries the table in the layout both read and
+    write, with no per-chunk relayout of the whole table; only the gathered
+    rows are moved to the ``(layers, lanes, ...)`` view that
+    ``decode_hidden_step`` takes, and back.  Module-level so
     :class:`FlowEngine` and :class:`repro.serve.sharded_flow_engine
     .ShardedFlowEngine` run the *same* traced function — one shard of a
     sharded table is exactly a single-device table, which is what makes
@@ -137,7 +146,7 @@ def make_flow_step(
         int_score = resolve("flow_score", "int-emulation")
 
     def slotted(c) -> bool:
-        return c.ndim >= 2 and c.shape[1] == n_slots
+        return c.ndim >= 2 and c.shape[0] == n_slots
 
     def step(params, rules, caches, positions, sig, hidden_sum, vetoed,
              idx, tokens, fresh):
@@ -149,8 +158,9 @@ def make_flow_step(
         def take(c):
             if not slotted(c):
                 return c
-            f = fresh.reshape((1, -1) + (1,) * (c.ndim - 2))
-            return jnp.where(f, jnp.zeros_like(c[:, idx]), c[:, idx])
+            rows = c[idx]
+            f = fresh.reshape((-1,) + (1,) * (c.ndim - 1))
+            return jnp.moveaxis(jnp.where(f, jnp.zeros_like(rows), rows), 0, 1)
 
         cs = jax.tree_util.tree_map(take, caches)
         pos = jnp.where(fresh, 0, positions[idx])
@@ -182,7 +192,7 @@ def make_flow_step(
         out["sig"] = sg  # cumulative signature after this packet (drift stats)
 
         def put(c, u):
-            return c.at[:, idx].set(u) if slotted(c) else c
+            return c.at[idx].set(jnp.moveaxis(u, 1, 0)) if slotted(c) else c
 
         caches = jax.tree_util.tree_map(put, caches, cs)
         positions = positions.at[idx].set(pos)
@@ -192,6 +202,18 @@ def make_flow_step(
         return caches, positions, sig, hidden_sum, vetoed, out
 
     return step
+
+
+def init_flow_caches(arch, n_slots: int, max_len: int):
+    """The flow table's zeroed decode caches, slot-major: each leaf of
+    :func:`repro.models.model.init_caches` with its slot axis moved to the
+    front, ``(layers, slots, ...)`` -> ``(slots, layers, ...)`` (the layout
+    :func:`make_flow_step` gathers and scatters by slot).  Call it inside a
+    jit so the layer-major zeros are never materialised."""
+    return jax.tree_util.tree_map(
+        lambda c: jnp.moveaxis(c, 1, 0),
+        M.init_caches(arch, n_slots, max_len, dtype=jnp.float32),
+    )
 
 
 def stage_impls(ccfg: C.ClassifierConfig, backend: str,
@@ -503,7 +525,15 @@ def _engine_kwargs_from_program(program, backend: Optional[str] = None) -> Dict:
 
 
 class FlowEngine:
-    """Streaming per-flow classification over a bounded flow table."""
+    """Streaming per-flow classification over a bounded flow table.
+
+    The device table holds ``capacity + 1`` slots (the last is scratch for
+    padding lanes), every array slot-major: ``caches`` leaves
+    ``(slots, layers, ...)``, ``positions``, ``sig``, ``hidden_sum`` and
+    ``vetoed`` ``(slots, ...)``.  Each arrival round gathers and scatters
+    its rows by slot along that major axis, so the fused ingest loop
+    carries the table without relaying it out every chunk.
+    """
 
     def __init__(
         self,
@@ -543,11 +573,11 @@ class FlowEngine:
             deploy_ledger.raise_if_over()
 
         # slot-batched state: capacity real slots + one scratch slot that
-        # absorbs padding lanes (index == capacity)
+        # absorbs padding lanes (index == capacity); the caches slot-major
         self._n_slots = fcfg.capacity + 1
-        self.caches = M.init_caches(
-            arch, self._n_slots, fcfg.max_flow_tokens, dtype=jnp.float32
-        )
+        self.caches = jax.jit(
+            lambda: init_flow_caches(arch, self._n_slots, fcfg.max_flow_tokens)
+        )()
         W, d = ccfg.sig_words, arch.d_model
         self.positions = jnp.zeros((self._n_slots,), jnp.int32)
         self.sig = jnp.zeros((self._n_slots, W), jnp.uint32)

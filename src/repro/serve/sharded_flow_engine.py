@@ -52,12 +52,12 @@ from repro.core import symbolic
 from repro.core.hardware_model import DEFAULT_DATAPLANE
 from repro.data.pipeline import arrival_rounds, flow_shard
 from repro.launch.mesh import flow_shard_map
-from repro.models import model as M
 from repro.serve.flow_engine import (
     FlowEngineConfig,
     FlowStats,
     FlowTableDirectory,
     SwapRecord,
+    init_flow_caches,
     make_flow_step,
     resolve_swap,
     stage_impls,
@@ -189,9 +189,10 @@ class ShardedFlowEngine:
             self._int_tables = jax.device_put(self._int_tables, self._replicated)
 
         # per-shard slot-batched state (capacity real slots + one scratch
-        # slot absorbing padding lanes), stacked on a leading shard axis
-        # that shard_map splits over 'data'.  Built by one jit whose output
-        # is row-sharded, so each device only ever allocates its own rows.
+        # slot absorbing padding lanes), slot-major as in FlowEngine, stacked
+        # on a leading shard axis that shard_map splits over 'data'.  Built
+        # by one jit whose output is row-sharded, so each device only ever
+        # allocates its own rows.
         self._n_slots = fcfg.capacity + 1
         n = self._n_slots
         W, d = self.ccfg.sig_words, arch.d_model
@@ -199,7 +200,7 @@ class ShardedFlowEngine:
 
         def table():
             rows = (
-                M.init_caches(arch, n, fcfg.max_flow_tokens, dtype=jnp.float32),
+                init_flow_caches(arch, n, fcfg.max_flow_tokens),
                 jnp.zeros((n,), jnp.int32),
                 jnp.zeros((n, W), jnp.uint32),
                 jnp.zeros((n, d), hs_dtype),

@@ -137,20 +137,36 @@ class TestSnapshotInstall:
 
     def test_install_roundtrip_preserves_scores(self, classifier):
         """snapshot → install onto a FRESH same-shape engine reproduces
-        every per-flow score bit-exactly."""
-        svc = _service(classifier)
-        for b in _batches(4):
+        every per-flow score bit-exactly, and replays the same future
+        bit-exactly (capacity to spare: the install assigns fresh slots, so
+        an LRU tie under pressure could pick another victim).  The
+        snapshot's cache rows are ``(n, layers, ...)`` whatever the table's
+        own axis order."""
+        svc = _service(classifier, capacity=256)
+        batches = _batches(6)
+        for b in batches[:4]:
             svc.ingest(b["flow_ids"], b["tokens"])
         want = _all_scores(svc)
         snap = snapshot_flow_state(svc.engine)
+        n, layers = len(snap["fids"]), classifier[0].arch.n_groups
+        for rows, leaf in zip(jax.tree_util.tree_leaves(snap["caches"]),
+                              jax.tree_util.tree_leaves(svc.engine.caches)):
+            assert rows.shape == (n, layers) + leaf.shape[3:]
         fresh = _program(classifier).deploy(DeploySpec(
             engine="sharded", num_shards=1,
-            flow=FlowEngineConfig(capacity=64, lanes=8),
+            flow=FlowEngineConfig(capacity=256, lanes=8),
         ))
         install_flow_state(fresh, snap, tick=svc.engine._tick)
         assert sorted(fresh.flow_ids()) == sorted(want)
         for fid, scores in want.items():
             assert fresh.flow_scores(fid) == scores, fid
+        for i, b in enumerate(batches[4:]):
+            _assert_outputs_equal(
+                svc.ingest(b["flow_ids"], b["tokens"]),
+                fresh.ingest(b["flow_ids"], b["tokens"]),
+                context=f"post-install batch {i}",
+            )
+        assert svc.engine.stats.flows_evicted == fresh.stats.flows_evicted == 0
 
 
 # --------------------------------------------------------------------------

@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from repro.data.pipeline import FlowScenario, arrival_rounds
+from repro.models import model as M
 from repro.serve.flow_engine import FlowEngine, FlowEngineConfig
+from repro.serve.sharded_flow_engine import ShardedFlowEngine
 from repro.train import classifier as C
 
 KEY = jax.random.PRNGKey(0)
@@ -130,6 +132,51 @@ class TestEquivalence:
         np.testing.assert_allclose(stream["s_nn"], out["s_nn"][0], atol=2e-3)
         np.testing.assert_allclose(stream["trust"], out["trust"][0], atol=2e-3)
         assert stream["vetoed"] == bool(out["hard_hit"][0])
+
+
+def _table(kind, ccfg, params, rules, fcfg):
+    if kind == "sharded":
+        return ShardedFlowEngine(ccfg, params, rules, fcfg, num_shards=1)
+    return FlowEngine(ccfg, params, rules, fcfg)
+
+
+class TestTableLayout:
+    @pytest.mark.parametrize("kind", ["flow", "sharded"])
+    def test_slotted_leaves_lead_with_the_slot_axis(self, classifier, kind):
+        """Every cache leaf is stored slot-major, ``(slots, layers, ...)``
+        after a sharded table's shard axis, like the table's other arrays:
+        the layout the step gathers and scatters by slot."""
+        ccfg, params = classifier
+        fcfg = FlowEngineConfig(capacity=16, lanes=8)
+        eng = _table(kind, ccfg, params, C.default_rules(ccfg, jnp.asarray([400])),
+                     fcfg)
+        lead, n = (() if kind == "flow" else (1,)), fcfg.capacity + 1
+        model = jax.eval_shape(lambda: M.init_caches(
+            ccfg.arch, n, fcfg.max_flow_tokens, dtype=jnp.float32))
+        leaves = jax.tree_util.tree_leaves(eng.caches)
+        assert len(leaves) == len(jax.tree_util.tree_leaves(model))
+        for leaf, ref in zip(leaves, jax.tree_util.tree_leaves(model)):
+            layers, slots, *rest = ref.shape
+            assert leaf.shape == lead + (slots, layers, *rest)
+            assert leaf.dtype == ref.dtype
+        for a in (eng.positions, eng.sig, eng.hidden_sum, eng.vetoed):
+            assert a.shape[: len(lead) + 1] == lead + (n,)
+
+    @pytest.mark.parametrize("kind", ["flow", "sharded"])
+    def test_per_flow_state_bytes_at_published_widths(self, kind):
+        """One flow row of the served chimera-dataplane config is
+        1,590,397 B (device row + 8-byte host LRU stamp), whatever the
+        table's axis order."""
+        from repro.compile.passes import required_sig_words
+        from repro.launch.flow_serve import classifier_config
+
+        ccfg = classifier_config()
+        ccfg = dataclasses.replace(ccfg, sig_words=required_sig_words(
+            ccfg.arch.vocab_size, ccfg.marker_base))
+        params, _ = C.init_classifier(ccfg, KEY)
+        eng = _table(kind, ccfg, params, C.default_rules(ccfg, jnp.asarray([400])),
+                     FlowEngineConfig(capacity=1, lanes=8, backend="xla"))
+        assert eng.per_flow_state_bytes() == 1_590_397
 
 
 class TestBoundedState:

@@ -11,6 +11,7 @@ entry written for a described chip cannot be read back without one.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,27 +123,65 @@ def test_flow_ingest_score_stage_compiles(chip, full_width):
     assert "tpu_custom_call" in text
 
 
-def test_fused_pallas_tpu_ingest_holds_a_kernel(chip, full_width):
-    """The fused ``pallas-tpu`` flow step at full width (small capacity):
-    its score stage must compile to a Mosaic kernel, not to XLA."""
+def _fused_ingest_text(chip, full_width, n_slots, width, donate=()) -> str:
+    """Compiled HLO of the fused ``pallas-tpu`` flow step at full width over
+    ``n_slots`` table rows, one chunk bucket of 8 chunks of ``width`` lanes,
+    at the benchmark's ``highest`` matmul precision."""
     from repro.kernels.dispatch import apply_kernel_backend, resolve
-    from repro.models import model as M_
+    from repro.serve.flow_engine import init_flow_caches
 
     ccfg, params, rules = full_width
     arch, _ = apply_kernel_backend(ccfg.arch, "pallas-tpu")
     ccfg = dataclasses.replace(ccfg, arch=arch)
-    n_slots, width, chunks, pkt_len = 65, 16, 8, 16
-    caches = jax.eval_shape(
-        lambda: M_.init_caches(arch, n_slots, 1024, dtype=jnp.float32)
-    )
+    chunks, pkt_len = 8, 16
+    caches = jax.eval_shape(lambda: init_flow_caches(arch, n_slots, 1024))
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     fused = resolve("flow_ingest", "pallas-tpu")(ccfg, n_slots)
-    text = _compiled_text(
-        fused, _shapes(params, chip), _shapes(rules, chip),
-        _shapes(caches, chip), s((n_slots,), jnp.int32),
-        s((n_slots, ccfg.sig_words), jnp.uint32),
-        s((n_slots, arch.d_model), jnp.float32), s((n_slots,), bool),
-        s((chunks, width), jnp.int32), s((chunks, width, pkt_len), jnp.int32),
-        s((chunks, width), bool), s((), jnp.int32),
-    )
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fused, donate_argnums=donate).lower(
+            _shapes(params, chip), _shapes(rules, chip),
+            _shapes(caches, chip), s((n_slots,), jnp.int32),
+            s((n_slots, ccfg.sig_words), jnp.uint32),
+            s((n_slots, arch.d_model), jnp.float32), s((n_slots,), bool),
+            s((chunks, width), jnp.int32),
+            s((chunks, width, pkt_len), jnp.int32),
+            s((chunks, width), bool), s((), jnp.int32),
+        ).compile().as_text()
+
+
+def test_fused_pallas_tpu_ingest_holds_a_kernel(chip, full_width):
+    """The fused ``pallas-tpu`` flow step at full width (small capacity):
+    its score stage must compile to a Mosaic kernel, not to XLA."""
+    text = _fused_ingest_text(chip, full_width, n_slots=65, width=16)
     assert "tpu_custom_call" in text
+
+
+def _table_copies_outside_entry(text: str, n_slots: int):
+    """Names of the layout ``copy`` ops on a whole-table array (one with an
+    ``n_slots`` dimension) in any computation but ``ENTRY``.  The
+    ``copy-start``/``copy-done`` pairs that move arrays between memory
+    spaces are another opcode and are not counted."""
+    found, in_entry = [], False
+    for line in text.splitlines():
+        if line[:1] not in ("", " ", "}") and line.rstrip().endswith("{"):
+            in_entry = line.startswith("ENTRY")
+            continue
+        m = re.search(r"%(\S+) = \w+\[([\d,]*)\]\S* copy\(", line)
+        if m and not in_entry and str(n_slots) in m.group(2).split(","):
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("width", [8, 256])
+def test_fused_ingest_loop_carries_the_table_without_relayout(
+    chip, full_width, width
+):
+    """With the engine's donation of the table (args 2-6), the chunk loop
+    of the fused step holds no ``copy`` of a whole-table array: the table
+    is stored slot-major, the layout its gather and scatter by slot use, so
+    no chunk pays a relayout of every row."""
+    n_slots = 65
+    text = _fused_ingest_text(chip, full_width, n_slots, width,
+                              donate=(2, 3, 4, 5, 6))
+    assert " while(" in text  # the chunk loop is there to look inside
+    assert _table_copies_outside_entry(text, n_slots) == []
