@@ -26,6 +26,7 @@ class Context:
     lo: int  # traced window on the trace clock, ns
     hi: int
     chips: int
+    family: Any  # bench/models/<family>.py: the backbone's counts
     model: Dict[str, Any]
     classes: Dict[str, Any]
     pkt_len: int

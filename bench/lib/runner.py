@@ -1,11 +1,11 @@
 """One run of one cell: set-up, the loop's window, and what the readers and
 the output check need afterwards.
 
-Set-up builds the weights from the seed, compiles and deploys the program,
-generates the whole traffic stream, pre-fills the flow table and lets the
-cell's loop warm the shapes its traffic uses.  The loop (``bench/loops``)
-then submits calls for the window.  A fused engine is driven as
-``flow_serve --fused`` drives it, through the program's
+Set-up builds the weights from the seed by the cell's family, compiles and
+deploys the program, generates the whole traffic stream, pre-fills the flow
+table and lets the cell's loop warm the shapes its traffic uses.  The loop
+(``bench/loops``) then submits calls for the window.  A fused engine is
+driven as ``flow_serve --fused`` drives it, through the program's
 ``AsyncIngestPipeline``: a call is dispatched while up to ``in_flight``
 earlier calls (the traffic file) are still on the device, and its answers
 are read when its ring slot comes round again.  Every call is recorded (its
@@ -126,13 +126,15 @@ class Runner:
         capacity = d["capacity"] * d.get("num_shards", 1)
         self.stream = traffic.generate(self.traffic, self.classes, capacity, self.seed,
                                        self.stream_packets())
-        self.params = weights.make_params(self.model, self.cell.config["classifier"], self.seed)
-        self.ccfg = system.classifier_config(self.cell.config)
+        self.params = self.cell.family.make_params(self.model, self.cell.config["classifier"],
+                                                   self.seed)
+        self.ccfg = system.classifier_config(self.cell.config, self.cell.family)
         weights.check_layout(self.params, system.program_layout(self.ccfg))
         self.rule = weights.anomaly_rule(self.stream.anomaly_sig,
                                          sig_words(self.model, self.classes),
                                          self.classes["marker_base"])
-        self.program, self.engine = system.deploy(self.cell.config, self.params, self.rule)
+        self.program, self.engine = system.deploy(self.cell.config, self.ccfg, self.params,
+                                                  self.rule)
         if self.trace:
             for m in self.cell.per_layer:
                 reader = spec.load_module("metrics", m["name"])

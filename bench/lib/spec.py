@@ -7,9 +7,19 @@ per-layer metric sits in a file of its own, found here from its name:
 * traffic mix ``<name>``: ``bench/traffic/<name>.json``;
 * loop ``<name>`` (named by the traffic file): ``bench/loops/<name>.py``;
 * per-layer metric ``<name>``: ``bench/metrics/<name>.py``;
-* plain reference ``<name>`` (named by the configuration):
-  ``bench/reference/<name>.py``;
+* model family ``<name>`` (named by the configuration's ``"family"``):
+  ``bench/models/<name>.py``, everything the harness knows of one
+  architecture (``arch_config``, ``make_params``, ``token_flops``,
+  ``row_bytes``, ``weight_bytes``; see ``models/chimera_dataplane.py``);
+* plain reference ``<name>`` (named by the configuration's
+  ``"reference"``): ``bench/reference/<name>.py``;
 * output limits of configuration ``<name>``: ``bench/limits/<name>.json``.
+
+A configuration file's ``model`` holds the family's own keys, and these,
+which the harness reads whatever the family: ``vocab_size`` and
+``vocab_pad_multiple`` (the token alphabet, and with ``classifier``'s
+``marker_base`` the signature words), ``d_model`` (the pooled features the
+score stage reads) and ``dtype``.
 """
 
 from __future__ import annotations
@@ -29,6 +39,7 @@ class Cell:
     name: str
     chips: int
     config: Dict[str, Any]
+    family: Any  # the module bench/models/<config["family"]>.py
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]  # the end-to-end metrics this cell reports
     per_layer: List[Dict[str, Any]]  # the per-layer metrics this cell reports
@@ -47,6 +58,14 @@ def load_module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(config: Dict[str, Any]):
+    """The family module the configuration names by its ``"family"`` key."""
+    if "family" not in config:
+        raise KeyError(f"configuration {config.get('name')!r} names no \"family\": "
+                       f"give the module bench/models/<family>.py of its architecture")
+    return load_module("models", config["family"])
 
 
 def _in_cell(metric: Dict[str, Any], cell: str, e2e_names: List[str]) -> bool:
@@ -76,7 +95,7 @@ def make_cell(name: str, chips: int, config_file: str, traffic: str,
     """A cell from its configuration file and traffic name, with the limits
     of its configuration."""
     config = load_json(config_file)
-    return Cell(name=name, chips=chips, config=config,
+    return Cell(name=name, chips=chips, config=config, family=load_family(config),
                 traffic=load_json(os.path.join(BENCH_DIR, "traffic", f"{traffic}.json")),
                 end_to_end=end_to_end, per_layer=per_layer,
                 limits=load_json(os.path.join(BENCH_DIR, "limits", f"{config['name']}.json")))

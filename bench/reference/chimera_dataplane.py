@@ -6,9 +6,11 @@ nothing of the program.  Per flow, from its first packet in the table:
 
 * each of the ``n_layers`` blocks is pre-norm attention then a SwiGLU MLP,
   with RMSNorm (eps 1e-6) and a learned scale;
-* q, k, v are dense projections; q and k get rotary embeddings over
-  interleaved pairs at the flow's token position (theta ``rope_theta``),
-  then are scaled to norm ``input_scale``;
+* q, k, v are dense projections, q to ``n_heads`` heads and k, v to
+  ``n_kv_heads``; query head h reads kv head h // (n_heads / n_kv_heads),
+  its keys, values, state and global keys and values; q and k get rotary
+  embeddings over interleaved pairs at the flow's token position (theta
+  ``rope_theta``), then are scaled to norm ``input_scale``;
 * Chimera attention of token i: exact exp(q.k / sqrt(d_head)) attention
   over the earlier tokens of i's own chunk (``chunk_size`` tokens, i
   included), plus the linearized readout phi(q).S / phi(q).Z of every
@@ -23,6 +25,12 @@ nothing of the program.  Per flow, from its first packet in the table:
 
 The symbolic path (marker signature, TCAM match, veto, fusion) is exact and
 is worked out on the host by the output check.
+
+The layers are public, for another family's reference to import
+(``lib.spec.load_module("reference", "chimera_dataplane")``):
+``chimera_attention`` and ``swiglu_mlp`` each take a block's leaves and
+add their residual, and ``rms``, ``to_norm``, ``rope`` and ``phi`` are the
+pieces they are built of.
 
 ``dtype`` float32 runs every product at ``highest`` precision, the
 reference proper.  ``dtype`` bfloat16 runs the same equations with every
@@ -39,17 +47,17 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _rms(x, scale, eps=1e-6):
+def rms(x, scale, eps=1e-6):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _to_norm(x, r):
+def to_norm(x, r):
     n = jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True))
     return x * (r / jnp.maximum(n, 1e-6))
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (..., T, dh) with pairs (0,1), (2,3), ...; pos (..., T)."""
     dh = x.shape[-1]
     freqs = 1.0 / (theta ** (np.arange(0, dh, 2, dtype=np.float64) / dh))
@@ -59,65 +67,80 @@ def _rope(x, pos, theta):
     return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).reshape(x.shape)
 
 
-def _phi(x, w, r):
+def phi(x, w, r):
     """Random-feature map of (..., dh) inputs with rows w (m, dh)."""
-    xs = _to_norm(x, r) / (x.shape[-1] ** 0.25)
+    xs = to_norm(x, r) / (x.shape[-1] ** 0.25)
     sq = 0.5 * jnp.sum(xs * xs, axis=-1, keepdims=True)
     return jnp.exp(xs @ w.T - sq) / math.sqrt(w.shape[0])
 
 
-def _layer(model, p, x, S, Z, pos):
-    """One block over a chunk.  x (B, L, d); S (B, H, m, dv); Z (B, H, m)."""
+def _per_query_head(x, group: int, axis: int):
+    """Each of the ``group`` query heads of a kv head reads that kv head:
+    query head h reads kv head h // group."""
+    return x if group == 1 else jnp.repeat(x, group, axis=axis)
+
+
+def chimera_attention(model, p, x, S, Z, pos):
+    """Pre-norm Chimera attention over a chunk, with its residual: the
+    block's ``ln1`` and ``attn`` leaves.  x (B, L, d); S (B, Hkv, m, dv);
+    Z (B, Hkv, m); returns (x, S, Z), the state with the chunk folded in."""
     B, L, d = x.shape
-    H, dh = model["n_heads"], model["d_head"]
+    H, Hkv, dh = model["n_heads"], model["n_kv_heads"], model["d_head"]
+    group = H // Hkv
     fm = model["feature_map"]
     r = fm["input_scale"]
     a, ch = p["attn"], p["attn"]["chimera"]
 
-    h = _rms(x, p["ln1"]["scale"])
+    h = rms(x, p["ln1"]["scale"])
 
-    def heads(w):
-        return (h @ w).reshape(B, L, H, dh).transpose(0, 2, 1, 3)  # (B, H, L, dh)
+    def heads(w, n):
+        return (h @ w).reshape(B, L, n, dh).transpose(0, 2, 1, 3)  # (B, n, L, dh)
 
-    q = _rope(heads(a["wq"]["w"]), pos[:, None, :], model["rope_theta"])
-    k = _rope(heads(a["wk"]["w"]), pos[:, None, :], model["rope_theta"])
-    v = heads(a["wv"]["w"])
-    qh, kh = _to_norm(q, r), _to_norm(k, r)
-    pq, pk = _phi(qh, ch["fm"]["w"], r), _phi(kh, ch["fm"]["w"], r)
+    q = rope(heads(a["wq"]["w"], H), pos[:, None, :], model["rope_theta"])
+    k = rope(heads(a["wk"]["w"], Hkv), pos[:, None, :], model["rope_theta"])
+    v = heads(a["wv"]["w"], Hkv)
+    qh, kh = to_norm(q, r), to_norm(k, r)
+    pq, pk = phi(qh, ch["fm"]["w"], r), phi(kh, ch["fm"]["w"], r)
+    kq, vq = _per_query_head(kh, group, 1), _per_query_head(v, group, 1)
 
     # local: exact exp kernel over the chunk, causal
     causal = jnp.tril(jnp.ones((L, L), x.dtype))
-    s = jnp.exp(jnp.einsum("bhid,bhjd->bhij", qh, kh) / math.sqrt(dh)) * causal
-    num = jnp.einsum("bhij,bhjd->bhid", s, v)
+    s = jnp.exp(jnp.einsum("bhid,bhjd->bhij", qh, kq) / math.sqrt(dh)) * causal
+    num = jnp.einsum("bhij,bhjd->bhid", s, vq)
     den = jnp.sum(s, axis=-1)
     # stream: the folded earlier chunks
-    num = num + jnp.einsum("bhim,bhmd->bhid", pq, S)
-    den = den + jnp.einsum("bhim,bhm->bhi", pq, Z)
+    num = num + jnp.einsum("bhim,bhmd->bhid", pq, _per_query_head(S, group, 1))
+    den = den + jnp.einsum("bhim,bhm->bhi", pq, _per_query_head(Z, group, 1))
     # static globals behind the signature match
-    kg = _to_norm(ch["k_global"], r)  # (H, G, dh)
-    pg = _phi(kg, ch["fm"]["w"], r)
+    kg = to_norm(ch["k_global"], r)  # (Hkv, G, dh)
+    pg = _per_query_head(phi(kg, ch["fm"]["w"], r), group, 0)
     sig_q = (qh @ ch["sig_proj"] > 0).astype(jnp.int32)  # (B, H, L, bits)
-    sig_k = (kg @ ch["sig_proj"] > 0).astype(jnp.int32)  # (H, G, bits)
+    sig_k = _per_query_head((kg @ ch["sig_proj"] > 0).astype(jnp.int32), group, 0)  # (H, G, bits)
     ham = jnp.sum(jnp.abs(sig_q[:, :, :, None, :] - sig_k[None, :, None, :, :]), -1)
     match = (ham <= model["match_hamming"]).astype(x.dtype)
     sg = jnp.einsum("bhim,hgm->bhig", pq, pg) * match
-    num = num + jnp.einsum("bhig,hgd->bhid", sg, ch["v_global"])
+    num = num + jnp.einsum("bhig,hgd->bhid", sg, _per_query_head(ch["v_global"], group, 0))
     den = den + jnp.sum(sg, axis=-1)
     o = num / (den[..., None] + model["gamma"])
     x = x + o.transpose(0, 2, 1, 3).reshape(B, L, H * dh) @ a["wo"]["w"]
-
-    h = _rms(x, p["ln2"]["scale"])
-    mlp = p["mlp"]
-    y = (jax.nn.silu(h @ mlp["wg"]["w"]) * (h @ mlp["wi"]["w"])) @ mlp["wo"]["w"]
-    x = x + y
     S = S + jnp.einsum("bhjm,bhjd->bhmd", pk, v)
     Z = Z + jnp.sum(pk, axis=2)
     return x, S, Z
 
 
+def swiglu_mlp(p, x):
+    """Pre-norm SwiGLU MLP with its residual: the block's ``ln2`` and
+    ``mlp`` leaves.  x (B, L, d)."""
+    h = rms(x, p["ln2"]["scale"])
+    mlp = p["mlp"]
+    y = (jax.nn.silu(h @ mlp["wg"]["w"]) * (h @ mlp["wi"]["w"])) @ mlp["wo"]["w"]
+    return x + y
+
+
 def init_carry(model, lanes: int, dtype):
-    nl, H, dh, m = model["n_layers"], model["n_heads"], model["d_head"], model["feature_map"]["m"]
-    return (jnp.zeros((nl, lanes, H, m, dh), dtype), jnp.zeros((nl, lanes, H, m), dtype),
+    nl, Hkv, dh = model["n_layers"], model["n_kv_heads"], model["d_head"]
+    m = model["feature_map"]["m"]
+    return (jnp.zeros((nl, lanes, Hkv, m, dh), dtype), jnp.zeros((nl, lanes, Hkv, m), dtype),
             jnp.zeros((lanes,), jnp.int32), jnp.zeros((lanes, model["d_model"]), dtype))
 
 
@@ -142,10 +165,11 @@ def make_block(model, dtype):
         S_new, Z_new = [], []
         for layer in range(nl):
             p = jax.tree_util.tree_map(lambda w: w[layer], bb["blocks"]["b0"])
-            x, s_l, z_l = _layer(model, p, x, S[layer], Z[layer], pos)
+            x, s_l, z_l = chimera_attention(model, p, x, S[layer], Z[layer], pos)
+            x = swiglu_mlp(p, x)
             S_new.append(s_l)
             Z_new.append(z_l)
-        hf = _rms(x, bb["final_norm"]["scale"])
+        hf = rms(x, bb["final_norm"]["scale"])
         cum = hs[:, None, :] + jnp.cumsum(hf, axis=1)
         pooled = cum / (pos + 1).astype(dtype)[..., None]
         logits = pooled @ params["cls"]["w"]

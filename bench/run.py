@@ -106,7 +106,8 @@ def per_layer(r):
     shutil.rmtree(traced.dir, ignore_errors=True)
     lo, hi = trace.window(events)
     peaks = load_peaks(r.devices[0].device_kind)
-    ctx = readers.Context(events=events, lo=lo, hi=hi, chips=r.cell.chips, model=r.model,
+    ctx = readers.Context(events=events, lo=lo, hi=hi, chips=r.cell.chips,
+                          family=r.cell.family, model=r.model,
                           classes=r.classes, pkt_len=r.stream.pkt_len, peaks=peaks,
                           fids=r.stream.fids, window=r.window,
                           traced_calls=traced.calls)

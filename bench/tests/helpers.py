@@ -15,8 +15,12 @@ from lib import spec
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 
-def tiny_cell(mix: str = "zipf.backlog", shards: int = 1) -> spec.Cell:
+def tiny_cell(mix: str = "zipf.backlog", shards: int = 1, kv_heads: int = 0) -> spec.Cell:
+    """The tiny cell; ``kv_heads`` (when given) shares each kv head among
+    ``n_heads / kv_heads`` query heads."""
     cfg = spec.load_json(os.path.join(DATA, "tiny.json"))
+    if kv_heads:
+        cfg["model"]["n_kv_heads"] = kv_heads
     if shards > 1:
         cfg["deploy"].update(engine="sharded", fused=False, num_shards=shards)
     tr = dict(spec.load_json(os.path.join(spec.BENCH_DIR, "traffic", f"{mix}.json")))
@@ -24,8 +28,9 @@ def tiny_cell(mix: str = "zipf.backlog", shards: int = 1) -> spec.Cell:
     tr["warmup"] = {**tr["warmup"], "min_calls": 2, "quiet_calls": 2}
     bench = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
     e2e = [m for m in bench["end_to_end"] if m["name"] in ("pkts_per_s", "setup_s")]
-    return spec.Cell(name="tiny", chips=shards, config=cfg, traffic=tr, end_to_end=e2e,
-                     per_layer=[], limits=spec.load_json(os.path.join(DATA, "tiny_limits.json")))
+    return spec.Cell(name="tiny", chips=shards, config=cfg, family=spec.load_family(cfg),
+                     traffic=tr, end_to_end=e2e, per_layer=[],
+                     limits=spec.load_json(os.path.join(DATA, "tiny_limits.json")))
 
 
 def args(seed: int, seconds: float = 2.0):

@@ -71,6 +71,15 @@ def test_sound_run_is_correct(mix):
     assert res["attempted"] > 0 and res["failed"] == 0
 
 
+@pytest.mark.parametrize("mix", ["zipf.backlog", "flood.backlog"])
+def test_sound_grouped_query_run_is_correct(mix):
+    """Four query heads over two kv heads: the family's weights, the
+    program's grouped attention and the reference's agree."""
+    res = R.run(args(2**31 + 5), cell=tiny_cell(mix, kv_heads=2), require_tpu=False)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+
+
 def test_sound_sharded_run_is_correct():
     res = R.run(args(2**31 + 4), cell=tiny_cell(shards=4), require_tpu=False)
     assert res["correct"], res["checks"]

@@ -17,8 +17,8 @@ READERS = ("pack_us_per_pkt", "launch_us_per_pkt", "finalize_us_per_pkt")
 def _ctx(events, traced=((0, 100), (100, 250)), lo=0, hi=10_000):
     calls = [Call(a, b, 1) for a, b in traced]
     window = Window(calls=calls + [Call(250, 1000, 1)])
-    return readers.Context(events=events, lo=lo, hi=hi, chips=1, model={}, classes={},
-                           pkt_len=16, peaks={}, fids=np.zeros(1000, np.int64),
+    return readers.Context(events=events, lo=lo, hi=hi, chips=1, family=None, model={},
+                           classes={}, pkt_len=16, peaks={}, fids=np.zeros(1000, np.int64),
                            window=window, traced_calls=calls)
 
 
