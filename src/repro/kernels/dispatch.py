@@ -285,7 +285,8 @@ def _flow_score_reference(plan, tables, rules, hidden_sum, count, sig, sticky):
 
 # ==========================================================================
 # flow_ingest — canonical signature (a BUILDER, not the kernel itself):
-#   (ccfg: ClassifierConfig, n_slots: int, int_plan=None, *, tiles=None)
+#   (ccfg: ClassifierConfig, n_slots: int, max_len: int, int_plan=None, *,
+#    tiles=None)
 #     -> fused(params, rules, caches, positions, sig, hidden_sum, vetoed,
 #              idx (C,w) int32, tokens (C,w,pkt_len) int32, fresh (C,w) bool,
 #              n_chunks () int32)
@@ -302,25 +303,29 @@ def _flow_score_reference(plan, tables, rules, hidden_sum, count, sig, sticky):
 # ==========================================================================
 
 @register("flow_ingest", "reference")
-def _flow_ingest_reference(ccfg, n_slots, int_plan=None, *, tiles=None):
+def _flow_ingest_reference(ccfg, n_slots, max_len, int_plan=None, *,
+                           tiles=None):
     from repro.kernels.flow_ingest.ref import fused_ingest_ref
 
-    return fused_ingest_ref(ccfg, n_slots, int_plan=int_plan, tiles=tiles)
+    return fused_ingest_ref(ccfg, n_slots, max_len, int_plan=int_plan,
+                            tiles=tiles)
 
 
 @register("flow_ingest", "int-emulation")
-def _flow_ingest_int(ccfg, n_slots, int_plan=None, *, tiles=None):
+def _flow_ingest_int(ccfg, n_slots, max_len, int_plan=None, *, tiles=None):
     from repro.kernels.flow_ingest.ref import fused_ingest_ref
 
-    return fused_ingest_ref(ccfg, n_slots, int_plan=int_plan, tiles=tiles)
+    return fused_ingest_ref(ccfg, n_slots, max_len, int_plan=int_plan,
+                            tiles=tiles)
 
 
 def _flow_ingest_pallas(interpret: bool):
-    def impl(ccfg, n_slots, int_plan=None, *, tiles=None):
+    def impl(ccfg, n_slots, max_len, int_plan=None, *, tiles=None):
         from repro.kernels.flow_ingest.kernel import fused_ingest_pallas
 
         return fused_ingest_pallas(
-            ccfg, n_slots, int_plan=int_plan, tiles=tiles, interpret=interpret
+            ccfg, n_slots, max_len, int_plan=int_plan, tiles=tiles,
+            interpret=interpret,
         )
 
     return impl

@@ -241,7 +241,8 @@ def make_pallas_score_fn(ccfg, tiles=None, interpret: bool = False):
 
 
 def fused_ingest_pallas(
-    ccfg, n_slots: int, int_plan=None, *, tiles=None, interpret: bool = False
+    ccfg, n_slots: int, max_len: int, int_plan=None, *, tiles=None,
+    interpret: bool = False,
 ):
     """``flow_ingest`` builder for the Pallas backends.
 
@@ -254,8 +255,8 @@ def fused_ingest_pallas(
     from repro.serve.flow_engine import make_fused_ingest
 
     if int_plan is not None:
-        return make_fused_ingest(ccfg, n_slots, int_plan=int_plan)
+        return make_fused_ingest(ccfg, n_slots, max_len, int_plan=int_plan)
     return make_fused_ingest(
-        ccfg, n_slots,
+        ccfg, n_slots, max_len,
         score_fn=make_pallas_score_fn(ccfg, tiles=tiles, interpret=interpret),
     )

@@ -15,8 +15,9 @@ path has no tile knobs.
 from __future__ import annotations
 
 
-def fused_ingest_ref(ccfg, n_slots: int, int_plan=None, *, tiles=None):
+def fused_ingest_ref(ccfg, n_slots: int, max_len: int, int_plan=None, *,
+                     tiles=None):
     from repro.serve.flow_engine import make_fused_ingest
 
     del tiles  # performance knob of the Pallas backends only
-    return make_fused_ingest(ccfg, n_slots, int_plan=int_plan)
+    return make_fused_ingest(ccfg, n_slots, max_len, int_plan=int_plan)

@@ -240,7 +240,9 @@ def install_flow_state(
         if rows.ndim == 1 and rows.shape[0] == 0:
             return leaf  # non-slotted constant: keep the engine's copy
         h = np.zeros(leaf.shape, leaf.dtype)
-        h[s_idx, sl_idx] = rows
+        # rows in the table's stored per-slot shape; a snapshot taken before
+        # the lane-dense fold holds the same elements in the same order
+        h[s_idx, sl_idx] = rows.reshape(rows.shape[:1] + leaf.shape[2:])
         return jax.device_put(jnp.asarray(h), eng._row_sharded)
 
     new_state = (

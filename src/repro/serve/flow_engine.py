@@ -102,9 +102,11 @@ class SwapRecord:
 
 
 def make_flow_step(
-    ccfg: C.ClassifierConfig, n_slots: int, int_plan=None, *, score_fn=None
+    ccfg: C.ClassifierConfig, n_slots: int, max_len: int, int_plan=None, *,
+    score_fn=None,
 ):
-    """Build the jitted flow-table update step over ``n_slots`` table rows.
+    """Build the jitted flow-table update step over ``n_slots`` table rows
+    of decode caches built for ``max_len`` tokens (:func:`init_flow_caches`).
 
     One arrival round of lanes: gather the touched rows (lazily zeroing
     freshly-allocated slots), scan the packet tokens through
@@ -117,9 +119,15 @@ def make_flow_step(
     ``sig``, ``hidden_sum`` and ``vetoed``.  The gather ``c[idx]`` and the
     scatter ``c.at[idx].set`` then index the major axis of a row-major
     array, so the fused loop carries the table in the layout both read and
-    write, with no per-chunk relayout of the whole table; only the gathered
-    rows are moved to the ``(layers, lanes, ...)`` view that
-    ``decode_hidden_step`` takes, and back.  Module-level so
+    write, with no per-chunk relayout of the whole table.  A leaf whose two
+    minor dimensions are both narrower than a lane tile is stored with them
+    folded into rows of 128 lanes (:func:`stored_slot_shape`), so the whole
+    table enters and leaves a launch in that same layout; only the gathered
+    rows are reshaped to the leaf's logical per-slot shape (from
+    :func:`repro.models.model.init_caches`) and moved to the
+    ``(layers, lanes, ...)`` view that ``decode_hidden_step`` takes, and
+    back.  The fold keeps the row-major element order, so both reshapes
+    are exact.  Module-level so
     :class:`FlowEngine` and :class:`repro.serve.sharded_flow_engine
     .ShardedFlowEngine` run the *same* traced function — one shard of a
     sharded table is exactly a single-device table, which is what makes
@@ -145,6 +153,11 @@ def make_flow_step(
 
         int_score = resolve("flow_score", "int-emulation")
 
+    # each cache leaf at one slot, (layers, 1, ...): its logical row shape
+    model = jax.eval_shape(
+        lambda: M.init_caches(arch, 1, max_len, dtype=jnp.float32)
+    )
+
     def slotted(c) -> bool:
         return c.ndim >= 2 and c.shape[0] == n_slots
 
@@ -155,14 +168,14 @@ def make_flow_step(
 
         # gather the touched rows; zero lanes holding newly-alloc'd flows
         # (slot reuse after eviction must look like a fresh table entry)
-        def take(c):
+        def take(c, m):
             if not slotted(c):
                 return c
-            rows = c[idx]
-            f = fresh.reshape((-1,) + (1,) * (c.ndim - 1))
+            rows = c[idx].reshape(idx.shape + m.shape[:1] + m.shape[2:])
+            f = fresh.reshape((-1,) + (1,) * (rows.ndim - 1))
             return jnp.moveaxis(jnp.where(f, jnp.zeros_like(rows), rows), 0, 1)
 
-        cs = jax.tree_util.tree_map(take, caches)
+        cs = jax.tree_util.tree_map(take, caches, model)
         pos = jnp.where(fresh, 0, positions[idx])
         sg = jnp.where(fresh[:, None], jnp.uint32(0), sig[idx])
         hs_rows = hidden_sum[idx]
@@ -192,7 +205,10 @@ def make_flow_step(
         out["sig"] = sg  # cumulative signature after this packet (drift stats)
 
         def put(c, u):
-            return c.at[idx].set(jnp.moveaxis(u, 1, 0)) if slotted(c) else c
+            if not slotted(c):
+                return c
+            rows = jnp.moveaxis(u, 1, 0).reshape(idx.shape + c.shape[1:])
+            return c.at[idx].set(rows)
 
         caches = jax.tree_util.tree_map(put, caches, cs)
         positions = positions.at[idx].set(pos)
@@ -204,15 +220,41 @@ def make_flow_step(
     return step
 
 
+# lanes of a TPU vector register tile (the minor dimension of an (8, 128) tile)
+_LANE_TILE = 128
+
+
+def stored_slot_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The per-slot shape a slotted cache leaf is stored in, from its
+    logical per-slot shape: where the shape has two dimensions or more, both
+    of the last two are narrower than a lane tile and their product is a
+    multiple of it, those two are flattened into rows of 128 lanes,
+    ``(..., 64, 64)`` -> ``(..., 32, 128)``; every other shape is kept.
+    Otherwise the compiler gives a table leaf the compact layout with the
+    slot axis minor, and every launch relays the whole table out for its
+    gather and scatter by slot and back."""
+    if len(shape) >= 2 and max(shape[-2:]) < _LANE_TILE:
+        n = shape[-2] * shape[-1]
+        if n % _LANE_TILE == 0:
+            return shape[:-2] + (n // _LANE_TILE, _LANE_TILE)
+    return shape
+
+
 def init_flow_caches(arch, n_slots: int, max_len: int):
     """The flow table's zeroed decode caches, slot-major: each leaf of
     :func:`repro.models.model.init_caches` with its slot axis moved to the
     front, ``(layers, slots, ...)`` -> ``(slots, layers, ...)`` (the layout
-    :func:`make_flow_step` gathers and scatters by slot).  Call it inside a
-    jit so the layer-major zeros are never materialised."""
+    :func:`make_flow_step` gathers and scatters by slot), and each slot's
+    row reshaped to :func:`stored_slot_shape` of its logical shape.  The
+    elements and their row-major order are those of the slot-major leaf.
+    Call it inside a jit so the layer-major zeros are never materialised."""
+
+    def stored(c):
+        c = jnp.moveaxis(c, 1, 0)
+        return c.reshape(c.shape[:1] + stored_slot_shape(c.shape[1:]))
+
     return jax.tree_util.tree_map(
-        lambda c: jnp.moveaxis(c, 1, 0),
-        M.init_caches(arch, n_slots, max_len, dtype=jnp.float32),
+        stored, M.init_caches(arch, n_slots, max_len, dtype=jnp.float32)
     )
 
 
@@ -279,7 +321,8 @@ def pack_width_groups(
 
 
 def make_fused_ingest(
-    ccfg: C.ClassifierConfig, n_slots: int, int_plan=None, *, score_fn=None
+    ccfg: C.ClassifierConfig, n_slots: int, max_len: int, int_plan=None, *,
+    score_fn=None,
 ):
     """Build the fused whole-batch ingest step (``flow_ingest`` family).
 
@@ -305,7 +348,9 @@ def make_fused_ingest(
     per-chunk score outputs on a leading ``C`` axis (rows ≥ ``n_chunks``
     stay zero).
     """
-    step = make_flow_step(ccfg, n_slots, int_plan=int_plan, score_fn=score_fn)
+    step = make_flow_step(
+        ccfg, n_slots, max_len, int_plan=int_plan, score_fn=score_fn
+    )
 
     def fused(params, rules, caches, positions, sig, hidden_sum, vetoed,
               idx, tokens, fresh, n_chunks):
@@ -529,10 +574,12 @@ class FlowEngine:
 
     The device table holds ``capacity + 1`` slots (the last is scratch for
     padding lanes), every array slot-major: ``caches`` leaves
-    ``(slots, layers, ...)``, ``positions``, ``sig``, ``hidden_sum`` and
+    ``(slots, layers, ...)`` in the lane-dense per-slot shape of
+    :func:`stored_slot_shape`, ``positions``, ``sig``, ``hidden_sum`` and
     ``vetoed`` ``(slots, ...)``.  Each arrival round gathers and scatters
     its rows by slot along that major axis, so the fused ingest loop
-    carries the table without relaying it out every chunk.
+    carries the table without relaying it out every chunk, and a launch
+    takes it in as stored.
     """
 
     def __init__(
@@ -631,8 +678,8 @@ class FlowEngine:
                 )
             self._jit_fused = jax.jit(
                 resolve("flow_ingest", fam_backend)(
-                    self.ccfg, self._n_slots, int_plan=self._int_plan,
-                    tiles=tiles,
+                    self.ccfg, self._n_slots, fcfg.max_flow_tokens,
+                    int_plan=self._int_plan, tiles=tiles,
                 ),
                 donate_argnums=(2, 3, 4, 5, 6),
             )
@@ -753,7 +800,10 @@ class FlowEngine:
     # jitted hot path
     # ------------------------------------------------------------------
     def _make_step(self):
-        return make_flow_step(self.ccfg, self._n_slots, int_plan=self._int_plan)
+        return make_flow_step(
+            self.ccfg, self._n_slots, self.fcfg.max_flow_tokens,
+            int_plan=self._int_plan,
+        )
 
     def _step_rules(self):
         """The ``rules`` argument of the jitted step: the packed RuleSet,

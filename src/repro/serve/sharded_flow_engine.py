@@ -65,14 +65,14 @@ from repro.serve.flow_engine import (
 from repro.train import classifier as C
 
 
-def make_sharded_step(ccfg: C.ClassifierConfig, n_slots: int, mesh,
-                      int_plan=None):
+def make_sharded_step(ccfg: C.ClassifierConfig, n_slots: int, max_len: int,
+                      mesh, int_plan=None):
     """The per-round flow step of every shard in one ``shard_map`` over the
     mesh ``data`` axis: the single-device :func:`make_flow_step` applied to
     each device's own table rows (leading shard axis), with params and
     rules replicated.  Module-level so it can be compiled ahead of time for
     a described mesh."""
-    step = make_flow_step(ccfg, n_slots, int_plan=int_plan)
+    step = make_flow_step(ccfg, n_slots, max_len, int_plan=int_plan)
 
     def shard_step(params, rules, caches, positions, sig, hidden_sum,
                    vetoed, idx, tokens, fresh):
@@ -228,7 +228,8 @@ class ShardedFlowEngine:
         )
 
         self._jit_step = jax.jit(
-            make_sharded_step(self.ccfg, self._n_slots, mesh, self._int_plan),
+            make_sharded_step(self.ccfg, self._n_slots, fcfg.max_flow_tokens,
+                              mesh, self._int_plan),
             donate_argnums=(2, 3, 4, 5, 6),
         )
 

@@ -173,6 +173,48 @@ class TestSnapshotInstall:
 # reshard records + quiesce (single device)
 # --------------------------------------------------------------------------
 
+    def test_install_takes_cache_rows_in_the_logical_shape(self, classifier):
+        """A snapshot whose cache rows are in the model's logical per-slot
+        shape (``M.init_caches``, as a table without the lane-dense fold
+        holds them) installs to the same table: the fold keeps the elements
+        and their order, so scores and the replayed future are bit-exact."""
+        from repro.models import model as M
+
+        svc = _service(classifier, capacity=256)
+        batches = _batches(6)
+        for b in batches[:4]:
+            svc.ingest(b["flow_ids"], b["tokens"])
+        want = _all_scores(svc)
+        snap = snapshot_flow_state(svc.engine)
+        model = jax.eval_shape(lambda: M.init_caches(
+            classifier[0].arch, 1, svc.engine.fcfg.max_flow_tokens,
+            dtype=jnp.float32))
+        logical = jax.tree_util.tree_map(
+            lambda rows, m: rows.reshape((len(rows),) + m.shape[:1]
+                                         + m.shape[2:]),
+            snap["caches"], model)
+        assert any(a.shape != b.shape for a, b in zip(
+            jax.tree_util.tree_leaves(logical),
+            jax.tree_util.tree_leaves(snap["caches"])))
+        fresh = _program(classifier).deploy(DeploySpec(
+            engine="sharded", num_shards=1,
+            flow=FlowEngineConfig(capacity=256, lanes=8),
+        ))
+        install_flow_state(fresh, dict(snap, caches=logical),
+                           tick=svc.engine._tick)
+        for a, b in zip(jax.tree_util.tree_leaves(fresh.caches),
+                        jax.tree_util.tree_leaves(svc.engine.caches)):
+            assert a.shape == b.shape
+        for fid, scores in want.items():
+            assert fresh.flow_scores(fid) == scores, fid
+        for i, b in enumerate(batches[4:]):
+            _assert_outputs_equal(
+                svc.ingest(b["flow_ids"], b["tokens"]),
+                fresh.ingest(b["flow_ids"], b["tokens"]),
+                context=f"post-install batch {i}",
+            )
+
+
 class TestReshardControl:
     def test_same_count_reshard_is_noop(self, classifier):
         svc = _service(classifier)

@@ -11,7 +11,12 @@ import pytest
 
 from repro.data.pipeline import FlowScenario, arrival_rounds
 from repro.models import model as M
-from repro.serve.flow_engine import FlowEngine, FlowEngineConfig
+from repro.serve.flow_engine import (
+    FlowEngine,
+    FlowEngineConfig,
+    init_flow_caches,
+    stored_slot_shape,
+)
 from repro.serve.sharded_flow_engine import ShardedFlowEngine
 from repro.train import classifier as C
 
@@ -143,24 +148,133 @@ def _table(kind, ccfg, params, rules, fcfg):
 class TestTableLayout:
     @pytest.mark.parametrize("kind", ["flow", "sharded"])
     def test_slotted_leaves_lead_with_the_slot_axis(self, classifier, kind):
-        """Every cache leaf is stored slot-major, ``(slots, layers, ...)``
-        after a sharded table's shard axis, like the table's other arrays:
-        the layout the step gathers and scatters by slot."""
+        """Every cache leaf is stored slot-major, ``(slots, ...)`` after a
+        sharded table's shard axis, like the table's other arrays: exactly
+        the elements of ``M.init_caches`` moved slot-major, each slot's row
+        reshaped by :func:`stored_slot_shape` -- lane-dense where both minor
+        dimensions are narrower than a lane tile and their product fills
+        whole tiles (here S, k_buf and v_buf, 16 x 16), unchanged otherwise
+        (Z, count)."""
         ccfg, params = classifier
         fcfg = FlowEngineConfig(capacity=16, lanes=8)
         eng = _table(kind, ccfg, params, C.default_rules(ccfg, jnp.asarray([400])),
                      fcfg)
         lead, n = (() if kind == "flow" else (1,)), fcfg.capacity + 1
-        model = jax.eval_shape(lambda: M.init_caches(
-            ccfg.arch, n, fcfg.max_flow_tokens, dtype=jnp.float32))
+        model = M.init_caches(ccfg.arch, n, fcfg.max_flow_tokens,
+                              dtype=jnp.float32)
+        stored = jax.tree_util.tree_map(lambda c: c.shape[len(lead) + 1:],
+                                        eng.caches)
+        assert vars(stored["b0"]) == {
+            "S": (2, 2, 2, 128), "Z": (2, 2, 16), "k_buf": (2, 2, 2, 128),
+            "v_buf": (2, 2, 2, 128), "count": (2,),
+        }
         leaves = jax.tree_util.tree_leaves(eng.caches)
         assert len(leaves) == len(jax.tree_util.tree_leaves(model))
         for leaf, ref in zip(leaves, jax.tree_util.tree_leaves(model)):
             layers, slots, *rest = ref.shape
-            assert leaf.shape == lead + (slots, layers, *rest)
+            assert leaf.shape == lead + (slots,) + stored_slot_shape(
+                (layers, *rest))
             assert leaf.dtype == ref.dtype
+            np.testing.assert_array_equal(
+                np.asarray(leaf).reshape(lead + (slots, layers, *rest)),
+                np.broadcast_to(np.moveaxis(np.asarray(ref), 1, 0),
+                                lead + (slots, layers, *rest)))
         for a in (eng.positions, eng.sig, eng.hidden_sum, eng.vetoed):
             assert a.shape[: len(lead) + 1] == lead + (n,)
+
+    @pytest.mark.parametrize("logical,stored", [
+        ((4, 4, 64, 64), (4, 4, 32, 128)),  # the published k/v rings
+        ((2, 2, 16, 16), (2, 2, 2, 128)),
+        ((4, 32), (1, 128)),
+        ((4, 4, 256, 64), (4, 4, 256, 64)),  # S: m fills the tile already
+        ((4, 4, 256), (4, 4, 256)),  # Z
+        ((4, 4, 64, 128), (4, 4, 64, 128)),  # minor dimension fills a tile
+        ((2, 2, 16), (2, 2, 16)),  # 32 elements: no whole tile
+        ((4, 48, 24), (4, 9, 128)),
+        ((4,), (4,)),  # count: one dimension
+    ])
+    def test_stored_slot_shape_rule(self, logical, stored):
+        assert stored_slot_shape(logical) == stored
+
+    def test_ring_leaves_lane_dense_at_published_widths(self):
+        """At the served widths the 64 x 64 k/v rings are stored in rows of
+        128 lanes; S (m = 256) and Z, whose minor dimensions already fill a
+        lane tile, keep their shapes."""
+        from repro.launch.flow_serve import classifier_config
+
+        arch = classifier_config().arch
+        caches = jax.eval_shape(lambda: init_flow_caches(arch, 3, 1024))
+        shapes = jax.tree_util.tree_map(lambda c: c.shape, caches)
+        assert vars(shapes["b0"]) == {
+            "S": (3, 4, 4, 256, 64), "Z": (3, 4, 4, 256),
+            "k_buf": (3, 4, 4, 32, 128), "v_buf": (3, 4, 4, 32, 128),
+            "count": (3, 4),
+        }
+
+    def test_stored_rows_hold_the_logical_decode_state(self, classifier):
+        """A flow's stored row, read back through the logical shape, is the
+        decode state of its tokens scanned one at a time through
+        ``decode_hidden_step`` on ``M.init_caches`` leaves: the lane-dense
+        fold keeps the row-major element order."""
+        ccfg, params = classifier
+        eng = _engine(classifier)
+        rng = np.random.default_rng(4)
+        toks = rng.integers(0, 512, (3, 8)).astype(np.int32)
+        eng.ingest(np.array([7, 9, 7]), toks)
+        slot = eng.table.slot_of[7]
+
+        caches = M.init_caches(ccfg.arch, 1, eng.fcfg.max_flow_tokens,
+                               dtype=jnp.float32)
+        flow = np.concatenate([toks[0], toks[2]])
+        for pos, tok in enumerate(flow):
+            _, caches = M.decode_hidden_step(
+                ccfg.arch, params["backbone"], jnp.asarray([tok]),
+                jnp.asarray([pos]), caches)
+        for leaf, ref in zip(jax.tree_util.tree_leaves(eng.caches),
+                             jax.tree_util.tree_leaves(caches)):
+            logical = ref.shape[:1] + ref.shape[2:]
+            np.testing.assert_allclose(
+                np.asarray(leaf)[slot].reshape(logical),
+                np.asarray(ref)[:, 0], rtol=1e-5, atol=1e-6)
+
+    def test_lane_dense_replay_identical_fused_per_round_and_sharded(
+        self, classifier
+    ):
+        """Where the storage rule folds leaves (S, k_buf, v_buf here), the
+        fused ingest, the per-round ingest and a one-shard sharded replay
+        serve bit-identical answers and leave bit-identical tables."""
+        ccfg, params = classifier
+        sc = FlowScenario(kind="mix", vocab_size=512, pkt_len=8,
+                          packets_per_batch=48, seed=5)
+        rules = C.default_rules(ccfg, jnp.asarray(sc.anomaly_signature))
+        engines = [
+            FlowEngine(ccfg, params, rules,
+                       FlowEngineConfig(capacity=128, lanes=16)),
+            FlowEngine(ccfg, params, rules,
+                       FlowEngineConfig(capacity=128, lanes=16, fused=True)),
+            ShardedFlowEngine(ccfg, params, rules,
+                              FlowEngineConfig(capacity=128, lanes=16),
+                              num_shards=1),
+        ]
+        assert any(leaf.shape[-1] == 128
+                   for leaf in jax.tree_util.tree_leaves(engines[0].caches))
+        for _ in range(3):
+            b = sc.next_batch()
+            outs = [e.ingest(b["flow_ids"], b["tokens"]) for e in engines]
+            for o in outs[1:]:
+                for k in ("trust", "vetoed", "pred", "s_nn", "s_sym", "sig"):
+                    np.testing.assert_array_equal(outs[0][k], o[k], err_msg=k)
+        assert all(e.stats.flows_evicted == 0 for e in engines)
+        single, fused, sharded = engines
+        rows = np.array(sorted(single.table.slot_of.values()))
+        assert sorted(fused.table.slot_of.values()) == rows.tolist()
+        assert sorted(sharded.tables[0].slot_of.values()) == rows.tolist()
+        for a, b, c in zip(jax.tree_util.tree_leaves(single.caches),
+                           jax.tree_util.tree_leaves(fused.caches),
+                           jax.tree_util.tree_leaves(sharded.caches)):
+            np.testing.assert_array_equal(np.asarray(a)[rows], np.asarray(b)[rows])
+            np.testing.assert_array_equal(np.asarray(a)[rows],
+                                          np.asarray(c)[0][rows])
 
     @pytest.mark.parametrize("kind", ["flow", "sharded"])
     def test_per_flow_state_bytes_at_published_widths(self, kind):

@@ -136,7 +136,7 @@ def _fused_ingest_text(chip, full_width, n_slots, width, donate=()) -> str:
     chunks, pkt_len = 8, 16
     caches = jax.eval_shape(lambda: init_flow_caches(arch, n_slots, 1024))
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    fused = resolve("flow_ingest", "pallas-tpu")(ccfg, n_slots)
+    fused = resolve("flow_ingest", "pallas-tpu")(ccfg, n_slots, 1024)
     with jax.default_matmul_precision("highest"):
         return jax.jit(fused, donate_argnums=donate).lower(
             _shapes(params, chip), _shapes(rules, chip),
@@ -185,3 +185,32 @@ def test_fused_ingest_loop_carries_the_table_without_relayout(
                               donate=(2, 3, 4, 5, 6))
     assert " while(" in text  # the chunk loop is there to look inside
     assert _table_copies_outside_entry(text, n_slots) == []
+
+
+def _cache_leaf_copies(text: str, n_slots: int):
+    """Names of the layout ``copy`` ops, in any computation ``ENTRY``
+    included, on a float array of rank 4 or more with an ``n_slots``
+    dimension: a whole-table decode-cache leaf, relaid out as a launch
+    enters or leaves."""
+    found = []
+    for m in re.finditer(r"%(\S+) = f\d+\[([\d,]*)\]\S* copy\(", text):
+        dims = m.group(2).split(",")
+        if len(dims) >= 4 and str(n_slots) in dims:
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("width", [8, 256])
+def test_fused_ingest_launch_takes_the_cache_table_as_stored(
+    chip, full_width, width
+):
+    """With the engine's donation of the table (args 2-6), no computation
+    of the fused step, ``ENTRY`` included, copies a whole-table cache leaf:
+    the k/v rings are stored lane-dense (``stored_slot_shape``), so they
+    enter the launch in the layout its chunk loop carries, and no launch
+    relays the whole ring table out and back."""
+    n_slots = 65
+    text = _fused_ingest_text(chip, full_width, n_slots, width,
+                              donate=(2, 3, 4, 5, 6))
+    assert " while(" in text
+    assert _cache_leaf_copies(text, n_slots) == []
